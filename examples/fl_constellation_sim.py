@@ -44,6 +44,7 @@ import jax
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import MNIST_CNN
 from repro.core import (FLSimulation, SimConfig, convergence_time,
                         paper_constellation)
@@ -54,6 +55,7 @@ from repro.models import cnn
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--schemes", nargs="+", default=["asyncfleo-hap", "fedhap"],
                     choices=sorted(STRATEGIES))

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.modelbank import ModelBank
+from repro.core.modelbank import HIGHEST, ModelBank
 
 
 @dataclasses.dataclass
@@ -62,12 +62,12 @@ def dedup(models, metas: List[SatelliteMeta]):
 
 @jax.jit
 def _wsum_flat(stack, w, base, bw):
-    return bw * base + w @ stack
+    return bw * base + jnp.dot(w, stack, precision=HIGHEST)
 
 
 @jax.jit
 def _wsum_flat_nobase(stack, w):
-    return w @ stack
+    return jnp.dot(w, stack, precision=HIGHEST)
 
 
 def _flat_base(bank: ModelBank, base):

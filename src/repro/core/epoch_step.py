@@ -58,11 +58,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.modelbank import FlatSpec
+from repro.core.modelbank import HIGHEST, FlatSpec
 
 # Straggler matrices are padded up to at least this many rows so the fused
 # program keeps one trace across the common 0..4-straggler epochs.
 CARRY_MIN_ROWS = 4
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=HIGHEST)
 
 
 def next_pow2(n: int) -> int:
@@ -91,13 +95,11 @@ def sharded_contract(w: jnp.ndarray, stack: jnp.ndarray,
                      mesh: Mesh) -> jnp.ndarray:
     """(C,) @ (C, N) with the C axis sharded over "data": each device
     contracts its local rows, one psum combines the partials."""
-    from repro.shard_compat import shard_map
-
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("data"), P("data", None)),
                        out_specs=P(None), check_vma=False)
     def _contract(w_loc, s_loc):
-        return jax.lax.psum(w_loc @ s_loc, "data")
+        return jax.lax.psum(_dot(w_loc, s_loc), "data")
 
     return _contract(w, stack)
 
@@ -160,7 +162,7 @@ class EpochStepProgram:
             stack = jax.lax.with_sharding_constraint(
                 stack, bank_sharding(mesh))
             bank_term = sharded_contract(wv_bank, stack, mesh)
-            new_w = base_w * w_flat + bank_term + wv_carry @ carry
+            new_w = base_w * w_flat + bank_term + _dot(wv_carry, carry)
         elif self.use_kernel:
             # route eq. 14 through the fed_agg Pallas kernel, inlined into
             # the fused program: the bank pass folds in the (donated) base
@@ -169,7 +171,8 @@ class EpochStepProgram:
             new_w = agg_ops.fed_agg(stack, wv_bank, w_flat, base_w)
             new_w = agg_ops.fed_agg(carry, wv_carry, new_w, 1.0)
         else:
-            new_w = base_w * w_flat + wv_bank @ stack + wv_carry @ carry
+            new_w = (base_w * w_flat + _dot(wv_bank, stack)
+                     + _dot(wv_carry, carry))
         if kpad:
             c, n = stack.shape
             if blocked_m:
@@ -177,7 +180,8 @@ class EpochStepProgram:
                 # full-participation layout): one O(C*N) blocked einsum
                 pm = jnp.einsum("km,kmn->kn",
                                 dw_row.reshape(kpad, blocked_m),
-                                stack.reshape(kpad, blocked_m, n))
+                                stack.reshape(kpad, blocked_m, n),
+                                precision=HIGHEST)
             else:
                 # general layout: one-hot the segment ids into a dense
                 # (kpad+1, C) weight matrix on device and GEMM (the +1
@@ -186,8 +190,8 @@ class EpochStepProgram:
                 w_mat = (jax.nn.one_hot(dw_seg, kpad + 1,
                                         dtype=jnp.float32).T
                          * dw_row[None, :])
-                pm = (w_mat @ stack)[:kpad]
-            pm = pm + dw_carry @ carry
+                pm = _dot(w_mat, stack)[:kpad]
+            pm = pm + _dot(dw_carry, carry)
             dists = jnp.linalg.norm(pm - ref[None, :], axis=1)
         else:
             dists = jnp.zeros((0,), jnp.float32)

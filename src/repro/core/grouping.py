@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.modelbank import ModelBank
+from repro.core.modelbank import HIGHEST, ModelBank
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -27,7 +27,7 @@ def _blocked_distances(stack, w, ref, k):
     fused O(C*N) batched contraction (weights normalized per block)."""
     c, n = stack.shape
     pm = jnp.einsum("kc,kcn->kn", w.reshape(k, c // k),
-                    stack.reshape(k, c // k, n))
+                    stack.reshape(k, c // k, n), precision=HIGHEST)
     return jnp.linalg.norm(pm - ref[None, :], axis=1)
 
 
@@ -35,7 +35,9 @@ def _blocked_distances(stack, w, ref, k):
 def _dense_distances(weight_matrix, stack, ref):
     """General case: per-orbit weight rows -> partial models -> distances,
     one fused (K,C)x(C,N) contraction."""
-    return jnp.linalg.norm(weight_matrix @ stack - ref[None, :], axis=1)
+    return jnp.linalg.norm(
+        jnp.dot(weight_matrix, stack, precision=HIGHEST) - ref[None, :],
+        axis=1)
 
 
 def flatten_model(model) -> np.ndarray:
@@ -57,7 +59,7 @@ def partial_global_model(models, sizes: Sequence[float]):
     total = float(sum(sizes))
     if isinstance(models, ModelBank):
         ws = jnp.asarray(np.asarray(sizes, np.float32) / total)
-        return ws @ models.stack
+        return jnp.dot(ws, models.stack, precision=HIGHEST)
     ws = [s / total for s in sizes]
     return jax.tree.map(
         lambda *leaves: sum(w * np.asarray(l, dtype=np.float32)
@@ -267,7 +269,7 @@ class GroupingState:
                                       sizes, totals, stack.shape[0])
             if not W.any():
                 continue
-            term = jnp.asarray(W) @ stack
+            term = jnp.dot(jnp.asarray(W), stack, precision=HIGHEST)
             pm = term if pm is None else pm + term
         if pm is None:
             return out

@@ -26,6 +26,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Every contraction over the bank (eq. 14, grouping partial models) runs at
+# full f32 precision: XLA's TPU default is one bf16 pass, which moved the
+# grouping distances ~3e-3 relative on a v5e chip.  The CPU ignores it.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class FlatSpec:

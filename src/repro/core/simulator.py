@@ -220,6 +220,7 @@ class FLSimulation:
         self._pend_dev = None                            # (L, N) device
         self._pend_meta: List[tuple] = []      # (arrival_t, sat, epoch)
         self._spec = None              # FlatSpec of the stacked/fused path
+        self._w_flat = None            # its global model, flat on device
         self._fused_prog = None        # EpochStepProgram (fused path)
         # fused path: distances of newly seen orbits are fetched lazily —
         # (new_orbits, device dists, block map, block size), resolved at
@@ -829,6 +830,13 @@ class FLSimulation:
             self._spec = self._spec or FlatSpec.of(w0)
             self._w_flat = self._spec.flatten(w0)
         return bits, fused, stacked
+
+    def global_model(self):
+        """The global model the last ``run`` ended with: a pytree on the
+        device (the model-bank paths keep it as one flat vector)."""
+        if self._w_flat is None:
+            raise ValueError("only a model-bank run keeps the global model")
+        return self._spec.unflatten(self._w_flat)
 
     def _record_epoch(self, history: List[EpochRecord], beta: int,
                       t_agg: float, metas, info, lazy_eval: bool, w_tree):

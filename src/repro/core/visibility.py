@@ -397,6 +397,8 @@ class SparseVisibilityTimeline:
     def _point(self, j: int, key: np.ndarray, sats: np.ndarray,
                rows: np.ndarray) -> np.ndarray:
         """Window-containment test for node j at composite keys."""
+        if not len(self._klo[j]):       # node j never sees a satellite
+            return np.zeros(len(key), dtype=bool)
         i = np.searchsorted(self._klo[j], key, side="right") - 1
         ic = np.maximum(i, 0)
         return ((i >= 0) & (self._wsat[j][ic] == sats)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.shard_compat import shard_map
 
 from repro.optim import sgd, apply_updates
 
@@ -97,7 +96,7 @@ def make_fl_round(loss_fn: Callable, mesh: Mesh, *, local_iters: int = 4,
         return new_global, mean_loss
 
     batch_spec = P(axes if len(axes) > 1 else axes[0])
-    fl_round = shard_map(
+    fl_round = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), batch_spec, batch_spec),
         out_specs=(P(), P()),

@@ -4,8 +4,11 @@ Grid = (B*H, T/Lc); the chunk axis is innermost and sequential, carrying the
 (K, V) recurrent state in VMEM scratch across chunks of the same batch-head
 (re-seeded from the state0 input at chunk 0).  Per chunk: two (Lc,K)x(K,V)
 matmuls + one (Lc,K)x(K,Lc) masked matmul — MXU work — with the decay
-exponentials computed in f32 on the VPU.  See models/scan_ops.py for the
-math and the stabilization/clamp discussion.
+exponentials computed in f32 on the VPU.  Mosaic has no cumsum: the
+in-chunk prefix sums of the log decays are triangular matmuls and the
+state's per-row decay a diagonal one, at HIGHEST precision so they stay
+exact to f32 rounding.  See models/scan_ops.py for the math and the
+stabilization/clamp discussion.
 """
 from __future__ import annotations
 
@@ -15,6 +18,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _chunk_kernel(r_ref, k_ref, v_ref, ld_ref, s0_ref, u_ref,
@@ -31,29 +39,30 @@ def _chunk_kernel(r_ref, k_ref, v_ref, ld_ref, s0_ref, u_ref,
     v = v_ref[0].astype(jnp.float32)                      # (Lc, V)
     ld = ld_ref[0].astype(jnp.float32)                    # (Lc, K)
 
-    L = jnp.cumsum(ld, axis=0)
-    if include_current:
-        M = L
-    else:
-        M = jnp.concatenate([jnp.zeros((1, L.shape[1]), jnp.float32), L[:-1]], 0)
-    L_end = L[-1]                                         # (K,)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (Lc, Lc), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (Lc, Lc), 1)
+    L = _dot(jnp.where(rows >= cols, 1.0, 0.0), ld)      # inclusive cumsum
+    keep = (rows >= cols) if include_current else (rows > cols)
+    M = L if include_current else _dot(jnp.where(keep, 1.0, 0.0), ld)
+    L_end = jnp.sum(ld, axis=0, keepdims=True)           # (1, K)
 
     q_t = r * jnp.exp(M)
     k_t = k * jnp.exp(-L)
     y_cross = jnp.dot(q_t, S, preferred_element_type=jnp.float32)
     A = jnp.dot(q_t, k_t.T, preferred_element_type=jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (Lc, Lc), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (Lc, Lc), 1)
-    keep = (rows >= cols) if include_current else (rows > cols)
     A = jnp.where(keep, A, 0.0)
     y = y_cross + jnp.dot(A, v, preferred_element_type=jnp.float32)
     if not include_current:
-        u = u_ref[0].astype(jnp.float32)                  # (K,)
-        diag = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)
+        u = u_ref[0].astype(jnp.float32)                  # (1, K)
+        diag = jnp.sum(r * u * k, axis=1, keepdims=True)
         y = y + diag * v
 
-    k_carry = k * jnp.exp(L_end[None, :] - L)
-    S_new = (jnp.exp(L_end)[:, None] * S
+    k_carry = k * jnp.exp(L_end - L)
+    K = S.shape[0]
+    decay = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+                      == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1),
+                      jnp.exp(L_end), 0.0)                # diag(exp(L_end))
+    S_new = (_dot(decay, S)
              + jnp.dot(k_carry.T, v, preferred_element_type=jnp.float32))
     state[...] = S_new
     y_ref[0] = y.astype(y_ref.dtype)
@@ -69,6 +78,8 @@ def chunk_scan_flat(r, k, v, ld, s0, u, *, include_current: bool,
                     chunk: int, interpret: bool = True):
     """Flattened-batch-head form.
     r, k, ld: (BH, T, K); v: (BH, T, V); s0: (BH, K, V); u: (BH, K).
+    ``u`` enters the kernel as (BH, 1, K) so its block's last two dims equal
+    the array's, as Mosaic's (8, 128) tiling rule requires.
     Returns (y (BH, T, V), s_fin (BH, K, V))."""
     BH, T, K = r.shape
     V = v.shape[-1]
@@ -86,7 +97,7 @@ def chunk_scan_flat(r, k, v, ld, s0, u, *, include_current: bool,
             pl.BlockSpec((1, Lc, V), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, Lc, K), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, K, V), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, K), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, K), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, Lc, V), lambda i, j: (i, j, 0)),
@@ -98,5 +109,5 @@ def chunk_scan_flat(r, k, v, ld, s0, u, *, include_current: bool,
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, ld, s0, u)
+    )(r, k, v, ld, s0, u[:, None, :])
     return y, s_fin

@@ -22,6 +22,7 @@ BLOCK_N = 2048
 def _agg_kernel(w_ref, gamma_ref, base_ref, bw_ref, out_ref):
     # w_ref: (C, BLOCK_N) VMEM; gamma_ref: (1, C); base_ref/out_ref: (1, BLOCK_N)
     mixed = jnp.dot(gamma_ref[...], w_ref[...],
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32)        # (1, BLOCK_N)
     out_ref[...] = bw_ref[0, 0] * base_ref[...] + mixed
 

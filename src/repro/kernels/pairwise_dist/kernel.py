@@ -29,7 +29,8 @@ def _pdist_kernel(x_ref, out_ref, gram_acc, norm_acc):
         norm_acc[...] = jnp.zeros_like(norm_acc)
 
     xb = x_ref[...].astype(jnp.float32)                     # (M, BLOCK_N)
-    gram_acc[...] += jnp.dot(xb, xb.T, preferred_element_type=jnp.float32)
+    gram_acc[...] += jnp.dot(xb, xb.T, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
     norm_acc[...] += jnp.sum(xb * xb, axis=1, keepdims=True)
 
     @pl.when(i == pl.num_programs(0) - 1)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.shard_compat import shard_map
 
 
 def ep_capacity(tokens_local: int, top_k: int, n_ranks: int,
@@ -133,7 +132,7 @@ def make_ep_moe_layer(cfg: ModelConfig, mesh, *, axis_name: str = "model",
         p_specs_full = dict(p_specs)
         if "shared" in params:
             p_specs_full["shared"] = jax.tree.map(lambda _: P(), params["shared"])
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(p_specs_full, P("data", axis_name, None)),
             out_specs=(P("data", axis_name, None), P()),

@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs import ARCHS
 from repro.models import moe as MOE
+from repro.launch.mesh import make_mesh
 from repro.models.moe_ep import ep_capacity, make_ep_moe_layer
 
 
@@ -25,7 +26,7 @@ def test_ep_capacity_rounding():
 def test_ep_single_rank_matches_reference():
     cfg = ARCHS["deepseek-v2-236b"].reduced().replace(
         dtype="float32", moe_capacity_factor=64.0)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     key = jax.random.PRNGKey(0)
     p = MOE.init_moe_ffn(key, cfg)
     x = jax.random.normal(key, (2, 16, cfg.d_model)) * 0.5
@@ -46,7 +47,8 @@ MULTI_RANK_SCRIPT = textwrap.dedent("""
 
     cfg = ARCHS["deepseek-v2-236b"].reduced().replace(
         dtype="float32", moe_capacity_factor=64.0)     # 4 experts / 4 ranks
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     p = MOE.init_moe_ffn(key, cfg)
     x = jax.random.normal(key, (2, 16, cfg.d_model)) * 0.5

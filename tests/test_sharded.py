@@ -7,6 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.fl.sharded import make_fl_round
 from repro.launch import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import (BASE_RULES, FSDP_RULES, classify_leaf,
                                    partition_spec, tree_shardings)
 
@@ -65,7 +66,7 @@ def test_classify_known_leaves():
 
 
 def test_partition_spec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # model axis size 1: everything divides; use a fake 16-wide mesh check via
     # direct sizes by constructing the spec logic with a wider mesh if devices
     # allow — here we assert the no-crash property and correct axis names.
@@ -90,10 +91,10 @@ def test_tree_shardings_cover_params():
 def test_fsdp_rules_shard_embed_dim():
     """On a mesh with a >1 'data' axis, FSDP rules shard the embed dim."""
     if len(jax.devices()) < 2:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = partition_spec((256, 512), ("embed", "mlp"), mesh, FSDP_RULES)
         assert isinstance(spec, P)      # single device: still resolves
     else:
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         spec = partition_spec((256, 512), ("embed", "mlp"), mesh, FSDP_RULES)
         assert spec[0] == "data"

@@ -28,6 +28,10 @@ GEOMETRIES = {
     "walker200-ring4": (WalkerDelta(num_orbits=10, sats_per_orbit=20,
                                     altitude_m=600e3,
                                     inclination_deg=60.0), "hapring:4"),
+    # two of the four HAPs never see the one satellite: empty window lists
+    "single-sat-ring4": (WalkerDelta(num_orbits=1, sats_per_orbit=1,
+                                     altitude_m=500e3,
+                                     inclination_deg=40.0), "hapring:4"),
 }
 
 
