@@ -1,0 +1,9 @@
+"""device_idle: share of the traced window in which no operation ran on
+the device (1 - union of device-op intervals / window)."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if tr is None or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
