@@ -81,8 +81,8 @@ from repro.core import FLSimulation, SimConfig, convergence_time
 from repro.core.constellation import WalkerDelta
 from repro.core.links import LinkModel
 from repro.fl.strategies import get_strategy
-from repro.obs import (DispatchProfiler, Tracer, add_runtime_tracks,
-                       export_chrome, export_jsonl, validate_chrome_trace)
+from repro.obs import (Tracer, add_runtime_tracks, export_chrome,
+                       export_jsonl, validate_chrome_trace)
 from repro.obs.trace import SPAN_ROUND
 from repro.sched import EventDrivenRuntime
 
@@ -127,6 +127,13 @@ from repro.sweep.testbed import (ConvergingTrainer, MeanDistanceEvaluator,
                                  make_model)
 
 
+def program_counters(prog) -> Dict:
+    """The fused epoch program's dispatch and trace counters."""
+    return {k: int(getattr(prog, k)) for k in
+            ("dispatches", "fallback_dispatches", "batched_dispatches",
+             "traces")}
+
+
 def _run_policy(name: str, strategy: str, w0, target: float,
                 max_epochs: int, duration_s: float,
                 ps_channels: Optional[int] = None,
@@ -143,11 +150,10 @@ def _run_policy(name: str, strategy: str, w0, target: float,
         spec = dataclasses.replace(spec, ps_channels=ps_channels)
     if staleness_fn != "eq13":
         spec = dataclasses.replace(spec, staleness_fn=staleness_fn)
-    prof = DispatchProfiler()
     sim = SimConfig(duration_s=duration_s, dt_s=30.0, train_time_s=300.0,
                     use_model_bank=True, use_fused_step=True,
                     event_driven=True, link=link, fault_model=fault,
-                    tracer=tracer, profiler=prof)
+                    tracer=tracer)
     fls = FLSimulation(spec, ConvergingTrainer(w0),
                        MeanDistanceEvaluator(), sim)
     rt = EventDrivenRuntime(fls)
@@ -200,11 +206,11 @@ def _run_policy(name: str, strategy: str, w0, target: float,
             "adaptive_backoff": fault.adaptive_backoff,
         },
         "wall_s": wall,
-        # reproducibility + wall-clock attribution (DESIGN.md §12): the
-        # RNG seed this row trained under, and where the host time went —
-        # cold trace+compile vs steady-state dispatch (obs/profile.py)
+        # reproducibility (DESIGN.md §12): the RNG seed this row trained
+        # under, and the fused program's own counters (the row's trainer
+        # is fresh, so they count this run alone)
         "seed": int(sim.seed),
-        "profile": prof.summary(),
+        "profile": program_counters(fls._fused_prog),
         "plan": fls.plan.summary(),
     }
     return row, fls, rt, hist
@@ -505,7 +511,7 @@ def policy_sweep(w0, target: float, max_epochs: int, duration_s: float,
     target, final accuracy, aggregations, plus the draw spec) and the
     sweep-wide dispatch economy (logical = what the same scenarios cost
     sequentially, a parity invariant; physical = programs actually
-    launched, counted by the PR 8 DispatchProfiler).  Under
+    launched, counted by the batcher).  Under
     ``--fail-if-not-lower`` the async<sync and pipelined<=async gates
     move onto the p50 band, and physical < logical is itself a gate."""
     from repro.sweep import (DispatchBatcher, ScenarioSpec, grid,
@@ -515,8 +521,7 @@ def policy_sweep(w0, target: float, max_epochs: int, duration_s: float,
     base = ScenarioSpec(duration_s=duration_s, dt_s=30.0,
                         train_time_s=300.0, ps_channels=ps_channels)
     specs = grid(base, strategy=[s for _, s in rows], seed=seeds)
-    prof = DispatchProfiler()
-    batcher = DispatchBatcher(mode="exact", profiler=prof)
+    batcher = DispatchBatcher(mode="exact")
     t0 = time.perf_counter()
     results = run_scenarios(specs, w0, batched=True, max_epochs=max_epochs,
                             target_accuracy=target, batcher=batcher)
@@ -551,7 +556,6 @@ def policy_sweep(w0, target: float, max_epochs: int, duration_s: float,
             "logical_dispatches": logical,
             "physical_dispatches": batcher.physical_dispatches,
             "batcher": batcher.summary(),
-            "profile": prof.summary(),
         },
         "wall_s": wall,
     }

@@ -84,11 +84,17 @@ class Capture:
         prog._step = self
         self.want_hlo = want_hlo
         self.hlo = None
+        self.inputs_layout = None     # (placed, compiled-for) per leaf
         self.calls = []
 
     def __call__(self, *args):
         if self.want_hlo and self.hlo is None:
-            self.hlo = self.fn.lower(*args).compile().as_text()
+            compiled = self.fn.lower(*args).compile()
+            self.hlo = compiled.as_text()
+            self.inputs_layout = [
+                (leaf.sharding, want, leaf.ndim) for leaf, want in zip(
+                    jax.tree.leaves(args[2]),
+                    jax.tree.leaves(compiled.input_shardings[0][2]))]
         (w, carry, _inputs, _ids, _seed, wv_bank, wv_carry, base_w,
          dw_row, dw_seg, kpad, blocked_m, dw_carry, ref) = args
         host = {k: np.asarray(v, np.float64) for k, v in dict(
@@ -183,12 +189,9 @@ def phase_paper_run(cfg, dev, *, epochs: int = EPOCHS,
                     local_iters: int = LOCAL_ITERS):
     from repro.core import SimConfig
     from repro.fl import get_strategy
-    from repro.obs import DispatchProfiler
 
-    prof = DispatchProfiler(block=True)
     sim, w0, prog, cap, hist = paper_run(
-        cfg, get_strategy(STRATEGY),
-        SimConfig(event_driven=True, profiler=prof), epochs,
+        cfg, get_strategy(STRATEGY), SimConfig(event_driven=True), epochs,
         local_iters=local_iters)
     print(f"paper run: {STRATEGY} S={sim.constellation.num_sats} "
           f"{cfg.name} params={prog.spec.num_params} "
@@ -199,10 +202,8 @@ def phase_paper_run(cfg, dev, *, epochs: int = EPOCHS,
               f"gamma {r.gamma!r}", flush=True)
     losses = [np.asarray(out[3]) for (_h, _k, _b, out) in cap.calls]
     print(f"  losses per dispatch: {[float(l.mean()) for l in losses]}")
-    print(f"  compile seconds {prof.compile_s!r} "
-          f"(cold dispatches {prof.cold_dispatches}), steady seconds "
-          f"{prof.dispatch_s!r} (dispatches "
-          f"{prof.dispatches - prof.cold_dispatches})", flush=True)
+    print(f"  dispatches {prog.dispatches}, fallback dispatches "
+          f"{prog.fallback_dispatches}, traces {prog.traces}", flush=True)
     check(len(hist) == epochs, f"{len(hist)} of {epochs} epochs committed")
     check(all(np.isfinite(r.accuracy) for r in hist), "non-finite accuracy")
     check(all(np.all(np.isfinite(l)) for l in losses), "non-finite loss")
@@ -272,7 +273,7 @@ def phase_mesh(cfg, *, chips: int = 4, local_iters: int = LOCAL_ITERS):
                                       f"devices, want {chips}")
     sim_m, w0, _p, cap_m, _h = paper_run(
         cfg, spec, SimConfig(event_driven=True, mesh=mesh), 1,
-        local_iters=local_iters)
+        local_iters=local_iters, want_hlo=True)
     sim_1, _w, _p, _c, _h = paper_run(
         cfg, spec, SimConfig(event_driven=True), 1, w0=w0,
         local_iters=local_iters)
@@ -287,6 +288,13 @@ def phase_mesh(cfg, *, chips: int = 4, local_iters: int = LOCAL_ITERS):
     check(rows == [stack.shape[0] // chips],
           f"rows per device {rows}, want {stack.shape[0] // chips}")
     check(err <= MESH_RTOL, f"the mesh epoch strays {err} from one chip")
+    # the simulator puts the commit's inputs in the layout the program
+    # compiled for, so the call reshards nothing
+    spread = [len(placed.device_set) for placed, _w, _n in cap_m.inputs_layout]
+    print(f"mesh: inputs placed over {spread} devices", flush=True)
+    check(all(placed.is_equivalent_to(want, ndim)
+              for placed, want, ndim in cap_m.inputs_layout),
+          "the inputs are not in the layout the mesh program takes")
 
 
 def main(argv=None) -> int:

@@ -21,9 +21,13 @@ def configure_compile_cache() -> str:
     """Turn on the persistent cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    nothing is set here; otherwise the cache goes to ``.jax_cache/`` at the
-    root of the checkout.
+    no directory is set here; otherwise the cache goes to ``.jax_cache/``
+    at the root of the checkout.  The cache key includes the programs'
+    metadata: a profiler trace reads the fused program's named scopes
+    from its executable's op names, and a key without them would load an
+    executable compiled from the same program with other (stale) names.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

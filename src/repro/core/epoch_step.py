@@ -59,6 +59,17 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.modelbank import HIGHEST, FlatSpec
+from repro.obs.span import span
+
+# The fused program's parts, as named scopes in its HLO's op_name metadata
+# (DESIGN.md §12): local training (the unflatten of the global model and
+# the pool's training vmap), forming the flat (C, N) bank, eq. 14, and
+# the new orbits' partial models and grouping distances.
+SCOPE_TRAIN = "local_train"
+SCOPE_FLATTEN = "flatten"
+SCOPE_AGGREGATE = "aggregate"
+SCOPE_GROUP_DIST = "group_dist"
+SCOPES = (SCOPE_TRAIN, SCOPE_FLATTEN, SCOPE_AGGREGATE, SCOPE_GROUP_DIST)
 
 # Straggler matrices are padded up to at least this many rows so the fused
 # program keeps one trace across the common 0..4-straggler epochs.
@@ -104,15 +115,39 @@ def sharded_contract(w: jnp.ndarray, stack: jnp.ndarray,
     return _contract(w, stack)
 
 
+def _batch_leaf_sharding(leaf, mesh: Mesh,
+                         ndata: int) -> Optional[NamedSharding]:
+    """A batch leaf's leading (participant) axis over "data", where it
+    divides; None for a leaf that stays whole."""
+    if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] % ndata == 0:
+        return NamedSharding(mesh, P("data", *([None] * (leaf.ndim - 1))))
+    return None
+
+
 def _constrain_batch(inputs, mesh: Mesh, ndata: int):
     """Shard every batch leaf's leading (participant) axis over "data"."""
     def _c(leaf):
-        if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] % ndata == 0:
-            spec = P("data", *([None] * (leaf.ndim - 1)))
-            return jax.lax.with_sharding_constraint(
-                leaf, NamedSharding(mesh, spec))
-        return leaf
+        sharding = _batch_leaf_sharding(leaf, mesh, ndata)
+        return (leaf if sharding is None
+                else jax.lax.with_sharding_constraint(leaf, sharding))
     return jax.tree.map(_c, inputs)
+
+
+def put_inputs(inputs, mesh: Optional[Mesh], rows: int):
+    """Copy one epoch's gathered host inputs to the device(s) in the
+    layout the fused program takes them, so that its call copies nothing
+    again: where the program shards the batch (a data mesh whose axis
+    divides the ``rows`` padded participants), each batch leaf split over
+    "data" (``_constrain_batch``) and every other leaf replicated; else
+    on the default device, where the call would have put host arrays."""
+    ndata = _data_axis_size(mesh)
+    if ndata <= 1 or rows % ndata:
+        return jax.device_put(inputs)
+    whole = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda leaf: jax.device_put(
+            leaf, _batch_leaf_sharding(leaf, mesh, ndata) or whole),
+        inputs)
 
 
 @dataclasses.dataclass
@@ -128,13 +163,11 @@ class EpochStepProgram:
     mesh: Optional[Mesh] = None
     donate: bool = True
     use_kernel: bool = False           # fed_agg Pallas contraction (below)
-    # host-side dispatch timing (obs/profile.DispatchProfiler); None (the
-    # default) takes the exact pre-existing path — no timing, no overhead
-    profiler: Optional[Any] = None
 
     dispatches: int = 0                # fused one-dispatch epochs
     fallback_dispatches: int = 0       # epochs that needed train+agg split
     batched_dispatches: int = 0        # scenario-batched physical dispatches
+    traces: int = 0                    # JAX traces of the fused program
 
     def __post_init__(self):
         donate = (0,) if self.donate else ()
@@ -144,24 +177,51 @@ class EpochStepProgram:
 
     # ---- traced body -------------------------------------------------------
 
-    def _trace(self, w_flat, carry, inputs, ids, seed,
-               wv_bank, wv_carry, base_w, dw_row, dw_seg, kpad,
-               blocked_m, dw_carry, ref):
+    def _trace(self, *args):
+        """The jitted step's Python body: it runs only when JAX traces the
+        program (a new static signature), never at steady state."""
+        self.traces += 1
+        w_flat, carry, ids, kpad, blocked_m = (args[0], args[1], args[3],
+                                               args[10], args[11])
+        with span("fused_trace", carry_rows=int(carry.shape[0]),
+                  rows=int(ids.shape[0]), kpad=int(kpad),
+                  blocked_m=int(blocked_m), params=int(w_flat.shape[0])):
+            return self._body(*args)
+
+    def _body(self, w_flat, carry, inputs, ids, seed,
+              wv_bank, wv_carry, base_w, dw_row, dw_seg, kpad,
+              blocked_m, dw_carry, ref):
+        # the named scopes land in each HLO instruction's op_name metadata
+        # and nowhere else: a device trace charges every op to its part
         mesh, ndata = self.mesh, _data_axis_size(self.mesh)
         sharded = ndata > 1 and int(ids.shape[0]) % ndata == 0
-        if sharded:
-            inputs = _constrain_batch(inputs, mesh, ndata)
-        params = self.spec.unflatten(w_flat)
-        stacked, losses = self.train_fn(params, inputs, ids, seed)
-        stack = (stacked if getattr(stacked, "ndim", None) == 2
-                 else self.spec.flatten_stacked(stacked))
+        with jax.named_scope(SCOPE_TRAIN):
+            if sharded:
+                inputs = _constrain_batch(inputs, mesh, ndata)
+            params = self.spec.unflatten(w_flat)
+            stacked, losses = self.train_fn(params, inputs, ids, seed)
+        with jax.named_scope(SCOPE_FLATTEN):
+            stack = (stacked if getattr(stacked, "ndim", None) == 2
+                     else self.spec.flatten_stacked(stacked))
+            if sharded:
+                stack = jax.lax.with_sharding_constraint(
+                    stack, bank_sharding(mesh))
+        with jax.named_scope(SCOPE_AGGREGATE):
+            new_w = self._aggregate(w_flat, stack, carry, wv_bank, wv_carry,
+                                    base_w, sharded)
+        with jax.named_scope(SCOPE_GROUP_DIST):
+            dists = self._group_dists(stack, carry, dw_row, dw_seg, kpad,
+                                      blocked_m, dw_carry, ref)
+        return new_w, stack, dists, losses
+
+    def _aggregate(self, w_flat, stack, carry, wv_bank, wv_carry, base_w,
+                   sharded):
+        """Eq. 14: the new global model from the base, bank and carry."""
         if sharded:
             # the shard_map psum keeps the XLA contraction — the Pallas
             # kernel is single-device (per-shard pallas_call under
             # shard_map is future work; the flag is ignored here)
-            stack = jax.lax.with_sharding_constraint(
-                stack, bank_sharding(mesh))
-            bank_term = sharded_contract(wv_bank, stack, mesh)
+            bank_term = sharded_contract(wv_bank, stack, self.mesh)
             new_w = base_w * w_flat + bank_term + _dot(wv_carry, carry)
         elif self.use_kernel:
             # route eq. 14 through the fed_agg Pallas kernel, inlined into
@@ -173,6 +233,12 @@ class EpochStepProgram:
         else:
             new_w = (base_w * w_flat + _dot(wv_bank, stack)
                      + _dot(wv_carry, carry))
+        return new_w
+
+    @staticmethod
+    def _group_dists(stack, carry, dw_row, dw_seg, kpad, blocked_m,
+                     dw_carry, ref):
+        """New orbits' partial models and their distances to ``ref``."""
         if kpad:
             c, n = stack.shape
             if blocked_m:
@@ -192,10 +258,8 @@ class EpochStepProgram:
                          * dw_row[None, :])
                 pm = _dot(w_mat, stack)[:kpad]
             pm = pm + _dot(dw_carry, carry)
-            dists = jnp.linalg.norm(pm - ref[None, :], axis=1)
-        else:
-            dists = jnp.zeros((0,), jnp.float32)
-        return new_w, stack, dists, losses
+            return jnp.linalg.norm(pm - ref[None, :], axis=1)
+        return jnp.zeros((0,), jnp.float32)
 
     # ---- scenario batch axis (DESIGN.md §13) -------------------------------
 
@@ -213,20 +277,24 @@ class EpochStepProgram:
         order, so it is NOT bit-exact (~1e-6 on new_w on CPU); that is the
         opt-in ``mode="vmap"`` below, never the parity default.
         """
+        self.traces += 1
         outs = []
-        for i in range(w_stack.shape[0]):
-            inp = (None if inputs is None
-                   else jax.tree.map(lambda l: l[i], inputs))
-            outs.append(self._trace(
-                w_stack[i], carry[i], inp, ids[i], seeds[i],
-                wv_bank[i], wv_carry[i], base_w[i], dw_row[i], dw_seg[i],
-                kpad, blocked_m, dw_carry[i], ref[i]))
+        with span("fused_trace", scenarios=int(w_stack.shape[0]),
+                  carry_rows=int(carry.shape[1]), rows=int(ids.shape[1]),
+                  kpad=int(kpad), blocked_m=int(blocked_m),
+                  params=int(w_stack.shape[1])):
+            for i in range(w_stack.shape[0]):
+                inp = (None if inputs is None
+                       else jax.tree.map(lambda l: l[i], inputs))
+                outs.append(self._body(
+                    w_stack[i], carry[i], inp, ids[i], seeds[i],
+                    wv_bank[i], wv_carry[i], base_w[i], dw_row[i],
+                    dw_seg[i], kpad, blocked_m, dw_carry[i], ref[i]))
         return tuple(jnp.stack(parts) for parts in zip(*outs))
 
     def batched_step(self, w_stack, carry, inputs, ids, seeds,
                      wv_bank, wv_carry, base_w, dw_row, dw_seg, kpad: int,
-                     blocked_m: int, dw_carry, ref, *,
-                     mode: str = "exact", fallback: bool = False):
+                     blocked_m: int, dw_carry, ref, *, mode: str = "exact"):
         """Dispatch B scenarios' epochs as one physical program.
 
         Every array carries a leading scenario axis B (batch leaves of
@@ -257,21 +325,9 @@ class EpochStepProgram:
                              donate_argnums=donate, static_argnums=(10, 11))
             self._batched_fns[key] = fn
         self.batched_dispatches += 1
-        args = (w_stack, carry, inputs, ids, seeds, wv_bank, wv_carry,
-                base_w, dw_row, dw_seg, int(kpad), int(blocked_m),
-                dw_carry, ref)
-        prof = self.profiler
-        if prof is None:
-            return fn(*args)
-        sig = ("batched", mode, int(w_stack.shape[0]),
-               int(carry.shape[1]), int(ids.shape[1]), int(kpad),
-               int(blocked_m), bool(fallback))
-        t0 = prof.timer()
-        out = fn(*args)
-        if prof.block:
-            jax.block_until_ready(out)
-        prof.record(sig, bool(fallback), prof.timer() - t0)
-        return out
+        return fn(w_stack, carry, inputs, ids, seeds, wv_bank, wv_carry,
+                  base_w, dw_row, dw_seg, int(kpad), int(blocked_m),
+                  dw_carry, ref)
 
     # ---- dispatch ----------------------------------------------------------
 
@@ -297,26 +353,7 @@ class EpochStepProgram:
             self.fallback_dispatches += 1
         else:
             self.dispatches += 1
-        prof = self.profiler
-        if prof is None:
-            return self._step(
-                w_flat, carry, inputs,
-                jnp.asarray(ids_np, jnp.int32), np.uint32(seed),
-                jnp.asarray(np.asarray(wv_bank, np.float32)),
-                jnp.asarray(np.asarray(wv_carry, np.float32)),
-                np.float32(base_w),
-                jnp.asarray(np.asarray(dw_row, np.float32)),
-                jnp.asarray(np.asarray(dw_seg, np.int32)),
-                int(kpad), int(blocked_m),
-                jnp.asarray(np.asarray(dw_carry, np.float32)),
-                ref)
-        # the static dispatch signature: everything that forces a new jit
-        # trace — array shapes (carry rows, participant count), the static
-        # args and the fallback split.  First-seen = trace+compile.
-        sig = (int(carry.shape[0]), int(len(ids_np)), int(kpad),
-               int(blocked_m), bool(fallback))
-        t0 = prof.timer()
-        out = self._step(
+        return self._step(
             w_flat, carry, inputs,
             jnp.asarray(ids_np, jnp.int32), np.uint32(seed),
             jnp.asarray(np.asarray(wv_bank, np.float32)),
@@ -327,10 +364,6 @@ class EpochStepProgram:
             int(kpad), int(blocked_m),
             jnp.asarray(np.asarray(dw_carry, np.float32)),
             ref)
-        if prof.block:
-            jax.block_until_ready(out)
-        prof.record(sig, bool(fallback), prof.timer() - t0)
-        return out
 
 
 def make_epoch_program(trainer, params, mesh: Optional[Mesh] = None,

@@ -42,6 +42,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -57,6 +58,7 @@ from repro.core.propagation import PropagationModel
 from repro.core.topology import RingOfStars
 from repro.core.visibility import VisibilityTimeline
 from repro.fl.strategies import StrategySpec
+from repro.obs.span import span, tracing
 
 
 @dataclasses.dataclass
@@ -81,11 +83,9 @@ class SimConfig:
     # to the fault-free simulator (the parity contract)
     fault_model: Optional[object] = None
     # observability (obs/, DESIGN.md §12): a `obs.Tracer` records the
-    # event runtime's round lifecycle; a `obs.DispatchProfiler` times the
-    # fused program's host dispatches.  Both are strictly read-only —
-    # None (the defaults) attaches nothing and stays bit-identical
+    # event runtime's round lifecycle in simulated seconds.  Strictly
+    # read-only — None (the default) attaches nothing, bit-identical
     tracer: Optional[object] = None
-    profiler: Optional[object] = None
     # scenario-batched sweeps (sweep/batch.DispatchBatcher, DESIGN.md
     # §13): when set, `_init_run` wraps the fused program in the
     # batcher's proxy so this simulation's epoch dispatches multiplex
@@ -101,6 +101,16 @@ class SimConfig:
     # but cannot host fault grid-masks (eclipse/outage masks mutate the
     # dense grid in place), so those combinations raise at construction
     visibility: str = "dense"
+
+
+# FLSimulation.segment_seconds keys (host wall seconds; DESIGN.md §12):
+# contact-plan timing, stacked/legacy training, the fused step (its input
+# gather, the inputs' host-to-device copy and the jit dispatch), the
+# weight math, grouping (its blocking distance read), the straggler
+# carry, and evaluation (its blocking accuracy reads)
+SEGMENTS = ("timing", "train", "step", "agg", "group", "carry", "eval",
+            "input_gather", "input_put", "dispatch", "eval_read",
+            "dist_read")
 
 
 @dataclasses.dataclass
@@ -227,16 +237,19 @@ class FLSimulation:
         # the next grouping read so the next epoch's host timing overlaps
         # the device stream instead of draining it
         self._dist_pending = None
-        # wall-time attribution per host-side section (bench breakdown)
+        # wall-time attribution per host-side section (bench breakdown);
+        # the last five nest inside "step", "eval" and "group"
         self.segment_seconds: Dict[str, float] = {
-            k: 0.0 for k in ("timing", "train", "step", "agg", "group",
-                             "carry", "eval")}
+            k: 0.0 for k in SEGMENTS}
 
     @contextlib.contextmanager
-    def _seg(self, key: str):
+    def _seg(self, key: str, **args):
+        """Time a host section into ``segment_seconds[key]``, inside the
+        profiler span ``asyncfleo.<key>`` that carries ``args``."""
         t0 = time.perf_counter()
         try:
-            yield
+            with span(key, **args):
+                yield
         finally:
             self.segment_seconds[key] += time.perf_counter() - t0
 
@@ -348,7 +361,8 @@ class FLSimulation:
         self._dist_pending = None
         new_orbits, dists, block_of, blocked_m = pend
         with self._seg("group"):
-            ds_full = np.asarray(dists)          # tiny (kpad,) transfer
+            with self._seg("dist_read"):
+                ds_full = np.asarray(dists)      # tiny (kpad,) transfer
             if blocked_m:
                 ds = ds_full[[block_of[k] for k in range(len(new_orbits))]]
             else:
@@ -415,7 +429,8 @@ class FLSimulation:
         AND late-carried — are stamped with ``train_epoch``, so eq. 13's
         staleness discount and Alg. 2's fresh/stale selection see the
         model version the round actually started from."""
-        from repro.core.epoch_step import carry_capacity, next_pow2
+        from repro.core.epoch_step import (carry_capacity, next_pow2,
+                                           put_inputs)
 
         sim, spec = self.sim, self.spec
         if train_epoch is None:
@@ -530,12 +545,24 @@ class FLSimulation:
                 wv_carry = agg.scatter_weights(carry_rows, ws, cap)
 
         with self._seg("step"):
-            inputs = self.trainer.epoch_inputs(ids_np)
-            new_w, stack, dists, losses = prog.step(
-                self._w_flat, carry, inputs, ids_np, seed,
-                wv_bank, wv_carry, base_w, dw_row, dw_seg, kpad,
-                blocked_m, dw_carry, self.grouping._ref_device(),
-                fallback=fallback)
+            with self._seg("input_gather"):
+                inputs = self.trainer.epoch_inputs(ids_np)
+            # the inputs' host-to-device copy, made explicit and in the
+            # program's own layout: the jit call would make the same copy
+            # of the host arrays itself
+            nbytes = (sum(getattr(x, "nbytes", 0)
+                          for x in jax.tree.leaves(inputs))
+                      if tracing() else None)
+            with self._seg("input_put", bytes=nbytes):
+                inputs = put_inputs(inputs, self.sim.mesh, len(ids_np))
+            with self._seg("dispatch", participants=len(participants),
+                           rows=len(ids_np), carried=len(c_idx),
+                           carry_rows=cap, params=N, fallback=fallback):
+                new_w, stack, dists, losses = prog.step(
+                    self._w_flat, carry, inputs, ids_np, seed,
+                    wv_bank, wv_carry, base_w, dw_row, dw_seg, kpad,
+                    blocked_m, dw_carry, self.grouping._ref_device(),
+                    fallback=fallback)
 
         if new_orbits:
             # don't block here: the fetch resolves at the next grouping
@@ -589,8 +616,10 @@ class FLSimulation:
                                            carry_rows, sizes, totals,
                                            carry.shape[0])
                 pm = jnp.asarray(dw) @ carry
-                ds = np.asarray(jnp.linalg.norm(
-                    pm - self.grouping._ref_device()[None, :], axis=1))
+                ds = jnp.linalg.norm(
+                    pm - self.grouping._ref_device()[None, :], axis=1)
+                with self._seg("dist_read"):
+                    ds = np.asarray(ds)
                 self.grouping.assign_distances(new_orbits, ds)
             if spec.agg_mode == "asyncfleo" and spec.grouping:
                 groups = {}
@@ -811,10 +840,6 @@ class FLSimulation:
             fused = make_epoch_program(self.trainer, w0, mesh=self.sim.mesh,
                                        use_kernel=self.spec.use_agg_kernel)
             if fused is not None:
-                # dispatch profiling hook (obs/profile.py); programs are
-                # cached on the trainer, so (re)set it every run — None
-                # detaches a previous run's profiler
-                fused.profiler = getattr(self.sim, "profiler", None)
                 dispatcher = getattr(self.sim, "dispatcher", None)
                 if dispatcher is not None:
                     # scenario-batched sweep (DESIGN.md §13): route this
@@ -852,7 +877,8 @@ class FLSimulation:
             elif lazy_eval:
                 acc = self.evaluator.eval_async(w_tree)  # lazy device
             else:
-                acc = float(self.evaluator(w_tree))
+                with self._seg("eval_read"):
+                    acc = float(self.evaluator(w_tree))
         history.append(EpochRecord(beta, t_agg, acc, len(metas),
                                    float(info.get("gamma", 1.0)),
                                    int(info.get("stale_groups", 0))))
@@ -928,10 +954,14 @@ class FLSimulation:
             if target_accuracy is not None and acc >= target_accuracy:
                 break
         self._resolve_pending_dists()        # leave grouping state complete
-        with self._seg("eval"):
-            for rec in history:              # block once, at finalize time
-                rec.accuracy = float(rec.accuracy)
+        self._read_accuracies(history)
         return history
+
+    def _read_accuracies(self, history: List[EpochRecord]) -> None:
+        """Block once, at finalize time, on every accuracy still lazy."""
+        with self._seg("eval"), self._seg("eval_read"):
+            for rec in history:
+                rec.accuracy = float(rec.accuracy)
 
 
 def convergence_time(history: List[EpochRecord], target: float) -> Optional[float]:

@@ -115,6 +115,7 @@ import numpy as np
 
 from repro.core.modelbank import gather_rows
 from repro.obs.metrics import MetricRegistry, StatsView
+from repro.obs.span import span
 from repro.obs.trace import (EV_ARRIVAL, EV_COMMIT, EV_DISPATCH, EV_DROP,
                              EV_ENERGY_DEFER, EV_FAILOVER, EV_PS_DOWN,
                              EV_PS_UP, EV_REROUTE, EV_TRANSFER_FAILED,
@@ -253,6 +254,10 @@ class EventDrivenRuntime:
 
     def run(self, w0, max_epochs: int = 30,
             target_accuracy: Optional[float] = None):
+        with span("run"):
+            return self._run(w0, max_epochs, target_accuracy)
+
+    def _run(self, w0, max_epochs: int, target_accuracy: Optional[float]):
         fls = self.fls
         self.bits, prog, _stacked = fls._init_run(w0)
         if prog is None:
@@ -325,9 +330,7 @@ class EventDrivenRuntime:
         # at the last processed instant so every opened span exports
         tracer.close_open_spans(t_last)
         fls._resolve_pending_dists()       # leave grouping state complete
-        with fls._seg("eval"):
-            for rec in self.history:       # block once, at finalize time
-                rec.accuracy = float(rec.accuracy)
+        fls._read_accuracies(self.history)
         return self.history
 
     # ---- round opening -----------------------------------------------------
@@ -989,6 +992,13 @@ class EventDrivenRuntime:
     # ---- commit ------------------------------------------------------------
 
     def _commit(self, rnd: RoundState, t_agg: float, used, late) -> None:
+        with span("commit", epoch=self.beta, used=len(used), late=len(late),
+                  participants=(0 if rnd.committed
+                                else len(rnd.participants))):
+            self._commit_round(rnd, t_agg, used, late)
+
+    def _commit_round(self, rnd: RoundState, t_agg: float, used,
+                      late) -> None:
         fls, spec = self.fls, self.spec
         participants = rnd.participants if not rnd.committed else []
         ids_np = rnd.ids_np if not rnd.committed else np.zeros(0, np.int32)
@@ -1004,20 +1014,6 @@ class EventDrivenRuntime:
                 cross += int(ep != rnd.beta)
         self.stats["cross_round_adoptions"] += cross
         self.stats["arrivals_committed"] += len(used) + adopted
-        prof = getattr(self.prog, "profiler", None)
-        if prof is not None:
-            # dispatches-per-trigger attribution (obs/profile.py): the
-            # fused commit below issues 1 (fused) or 2 (fallback) device
-            # programs for this one aggregation trigger
-            prof.trigger()
-        # scenario-batched sweeps (DESIGN.md §13): this runtime is one of
-        # several whose dispatches multiplex through a shared
-        # DispatchBatcher; its profiler counts *physical* programs, so
-        # every scenario's trigger feeds the shared denominator
-        dispatcher = getattr(self.sim, "dispatcher", None)
-        bprof = getattr(dispatcher, "profiler", None)
-        if bprof is not None:
-            bprof.trigger()
         t_trigger = t_agg
         out = fls._fused_commit(self.prog, self.beta, ids_np, participants,
                                 t_agg, used, late, train_epoch=rnd.beta)

@@ -81,7 +81,7 @@ class _Request:
 
 class BatchedProgram:
     """Drop-in ``EpochStepProgram`` facade handed to one scenario's
-    simulator/runtime: same ``spec``/``profiler``/``step`` surface, same
+    simulator/runtime: same ``spec``/``step`` surface, same
     *logical* dispatch counters (``dispatches``/``fallback_dispatches``
     advance exactly as a sequential run's would — a parity invariant),
     but ``step`` routes through the shared :class:`DispatchBatcher`."""
@@ -96,14 +96,6 @@ class BatchedProgram:
     @property
     def spec(self):
         return self._inner.spec
-
-    @property
-    def profiler(self):
-        return self._inner.profiler
-
-    @profiler.setter
-    def profiler(self, value):
-        self._inner.profiler = value
 
     def _batchable(self) -> bool:
         return (self._key is not None and self._inner.mesh is None
@@ -138,11 +130,10 @@ class DispatchBatcher:
     only build arrays and force already-enqueued values.
     """
 
-    def __init__(self, mode: str = "exact", profiler=None):
+    def __init__(self, mode: str = "exact"):
         if mode not in ("exact", "vmap"):
             raise ValueError(f"unknown scenario batch mode {mode!r}")
         self.mode = mode
-        self.profiler = profiler       # obs.DispatchProfiler for *physical*
         self._cv = threading.Condition()
         self._pending: List[_Request] = []
         self._live = 0                 # registered, not yet finished
@@ -217,8 +208,6 @@ class DispatchBatcher:
                     r.event.set()
 
     def _execute(self, reqs: List[_Request]) -> None:
-        prof = self.profiler
-        t0 = prof.timer() if prof is not None else 0.0
         if len(reqs) == 1 or reqs[0].sig[0] is None:
             # singleton or unbatchable: the scenario's own program, its
             # own step() — bit-exact by construction
@@ -227,9 +216,6 @@ class DispatchBatcher:
                 self.physical_dispatches += 1
                 self.solo_dispatches += 1
             self.max_group = max(self.max_group, 1)
-            if prof is not None:
-                prof.record(("solo-group",) + reqs[0].sig[2:7],
-                            reqs[0].fallback, prof.timer() - t0)
             return
         prog = reqs[0].prog            # batch_key certifies equivalence
         cols = list(zip(*(r.args for r in reqs)))
@@ -246,16 +232,12 @@ class DispatchBatcher:
             jnp.asarray(np.asarray(cols[7], np.float32)),
             jnp.stack(cols[8]), jnp.stack(cols[9]), kpad, blocked_m,
             jnp.stack(cols[12]), jnp.stack(cols[13]),
-            mode=self.mode, fallback=reqs[0].fallback)
+            mode=self.mode)
         for j, r in enumerate(reqs):
             r.out = tuple(o[j] for o in out)
         self.physical_dispatches += 1
         self.batched_dispatches += 1
         self.max_group = max(self.max_group, len(reqs))
-        if prof is not None:
-            prof.record(("batched-group", self.mode, len(reqs))
-                        + reqs[0].sig[2:7],
-                        reqs[0].fallback, prof.timer() - t0)
 
     def summary(self) -> dict:
         return {"flushes": self.flushes,

@@ -84,7 +84,6 @@ def run_scenarios(specs: Sequence[ScenarioSpec], w0=None, *,
                   target_accuracy: Optional[float] = None,
                   trainer_factory: Optional[Callable] = None,
                   evaluator_factory: Optional[Callable] = None,
-                  profiler=None,
                   batcher: Optional[DispatchBatcher] = None
                   ) -> List[ScenarioResult]:
     """Run every scenario; return :class:`ScenarioResult` in spec order.
@@ -94,8 +93,7 @@ def run_scenarios(specs: Sequence[ScenarioSpec], w0=None, *,
     the (stateless) trainer shares its jitted program cache across
     scenarios, and its ``scenario_batch_key`` is what lets the batcher
     group them.  Pass ``batcher`` to inspect physical-dispatch telemetry
-    after the run (``batcher.summary()``); ``profiler`` (a PR 8
-    ``DispatchProfiler``) records per-physical-dispatch timing.
+    after the run (``batcher.summary()``).
     """
     w0 = w0 if w0 is not None else make_model()
     if trainer_factory is None:
@@ -104,7 +102,7 @@ def run_scenarios(specs: Sequence[ScenarioSpec], w0=None, *,
     if evaluator_factory is None:
         evaluator_factory = MeanDistanceEvaluator
     if batcher is None and batched:
-        batcher = DispatchBatcher(mode=mode, profiler=profiler)
+        batcher = DispatchBatcher(mode=mode)
     const_cache: Dict = {}
     builds = [_build(s, w0, trainer_factory(w0), evaluator_factory(),
                      batcher if batched else None, const_cache)

@@ -380,6 +380,19 @@ MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     for a, b in zip(outs["single"], outs["mesh"]):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
+    # the simulator's explicit input copy lands in the layout the mesh
+    # program compiles for, so the call does not reshard it
+    from repro.core.epoch_step import put_inputs
+    put = put_inputs(inputs, mesh, C)
+    compiled = prog._step.lower(
+        spec.flatten(w0), carry, put, jnp.asarray(ids), np.uint32(7),
+        jnp.asarray(wv), jnp.asarray(wc), np.float32(0.5),
+        jnp.asarray(dw_row), jnp.asarray(dw_seg), K, 0, jnp.asarray(dwc),
+        ref).compile()
+    assert put.sharding.spec == ("data",), put.sharding
+    assert compiled.input_shardings[0][2].is_equivalent_to(put.sharding,
+                                                           put.ndim)
+
     # end-to-end: a full simulation on the data mesh matches the
     # single-device run epoch for epoch
     from test_epoch_step import TinyFusedTrainer, W0

@@ -4,9 +4,9 @@ Covers: Tracer span/instant bookkeeping and the NullTracer off-switch,
 the bounded deterministic Histogram, the MetricRegistry-backed
 StatsView compat layer (key-for-key against the registry snapshot),
 ``contention_stats()`` on a fresh runtime, the pinned ``tracer=None``
-bit-parity contract, Chrome trace-event export + validation, JSONL
-round-tripping through the trace_report CLI loader, and the host-side
-dispatch profiler's cold-vs-steady split.
+bit-parity contract, Chrome trace-event export + validation, and JSONL
+round-tripping through the trace_report CLI loader.  The wall-clock
+``asyncfleo.*`` spans are covered in ``test_spans.py``.
 """
 import dataclasses
 import json
@@ -17,10 +17,9 @@ import pytest
 from repro.core import FLSimulation, SimConfig
 from repro.core.links import LinkModel
 from repro.fl import get_strategy
-from repro.obs import (NULL_TRACER, DispatchProfiler, Histogram,
-                       MetricRegistry, StatsView, Tracer,
-                       add_runtime_tracks, export_chrome, export_jsonl,
-                       validate_chrome_trace)
+from repro.obs import (NULL_TRACER, Histogram, MetricRegistry, StatsView,
+                       Tracer, add_runtime_tracks, export_chrome,
+                       export_jsonl, validate_chrome_trace)
 from repro.obs.trace import (EV_COMMIT, EV_DISPATCH, EV_TRANSFER_RETRY,
                              EV_TRIGGER, SPAN_CHANNEL, SPAN_OUTAGE,
                              SPAN_ROUND)
@@ -257,36 +256,3 @@ def test_jsonl_and_chrome_roundtrip_through_trace_report(tmp_path):
     util = "\n".join(ps_utilization(a))
     assert "busy" in util and "outage" in util
     assert "retries" in retry_report(a)[0]
-
-
-# ---- dispatch profiler ------------------------------------------------------
-
-def test_dispatch_profiler_cold_vs_steady_unit():
-    p = DispatchProfiler()
-    p.trigger()
-    p.record((4, 2, 2, 0, False), False, 0.50)   # cold: new signature
-    p.record((4, 2, 2, 0, False), False, 0.01)   # steady: cache hit
-    p.record((4, 3, 4, 0, True), True, 0.40)     # cold again + fallback
-    s = p.summary()
-    assert s["dispatches"] == 3 and s["cold_dispatches"] == 2
-    assert s["fallback_dispatches"] == 1
-    assert s["compile_s"] == pytest.approx(0.90)
-    assert s["dispatch_s"] == pytest.approx(0.01)
-    assert s["dispatches_per_trigger"] == 3.0
-    p.reset()
-    assert p.summary()["dispatches"] == 0
-
-
-def test_dispatch_profiler_wired_through_fused_commits():
-    prof = DispatchProfiler()
-    fls = _sim("asyncfleo-twohap", profiler=prof, spec_kw=PIPE)
-    hist = EventDrivenRuntime(fls).run(W0, max_epochs=6)
-    s = prof.summary()
-    assert s["triggers"] == len(hist)
-    assert s["dispatches"] >= len(hist)
-    assert 0 < s["cold_dispatches"] <= s["dispatches"]
-    assert s["compile_s"] + s["dispatch_s"] > 0.0
-    # profiler off: the program must shed the hook between runs
-    fls2 = _sim("asyncfleo-twohap", spec_kw=PIPE)
-    EventDrivenRuntime(fls2).run(W0, max_epochs=2)
-    assert fls2._fused_prog.profiler is None

@@ -29,15 +29,17 @@ def _hist_key(hist):
 
 
 def assert_batched_parity(specs, max_epochs=3, target=0.9, mode="exact",
-                          batcher=None):
+                          batcher=None, trainer_factory=None):
     """Run ``specs`` sequentially and batched; assert bit-identical
     per-scenario histories, weights and dispatch counts.  Returns
-    (sequential, batched, batcher) for callers that inspect more."""
+    (sequential, batched, batcher) for callers that inspect more;
+    ``trainer_factory`` builds the batched run's trainer."""
     seq = run_scenarios(specs, W0, batched=False, max_epochs=max_epochs,
                         target_accuracy=target)
     batcher = batcher or DispatchBatcher(mode=mode)
     bat = run_scenarios(specs, W0, batched=True, max_epochs=max_epochs,
-                        target_accuracy=target, batcher=batcher)
+                        target_accuracy=target, batcher=batcher,
+                        trainer_factory=trainer_factory)
     for s, b in zip(seq, bat):
         assert _hist_key(s.history) == _hist_key(b.history), s.spec
         assert np.array_equal(s.final_weights, b.final_weights), s.spec
@@ -198,19 +200,23 @@ def test_dispatch_economy_small():
 @pytest.mark.slow
 def test_dispatch_economy_64_scenarios():
     """The acceptance-criteria sweep: 64 scenarios complete in fewer
-    physical fused dispatches than 64 sequential runs, counted via the
-    PR 8 DispatchProfiler, with per-scenario parity intact."""
-    from repro.obs import DispatchProfiler
+    physical fused dispatches than 64 sequential runs, counted by the
+    shared fused program's own counters, with per-scenario parity
+    intact."""
     specs = grid(BASE, seed=list(range(32)),
                  strategy=["asyncfleo-gs", "fedisl"])
     assert len(specs) == 64
-    prof = DispatchProfiler()
-    batcher = DispatchBatcher(profiler=prof)
+    shared = ConvergingTrainer(W0)
+    batcher = DispatchBatcher()
     _, bat, _ = assert_batched_parity(specs, max_epochs=3,
-                                      batcher=batcher)
+                                      batcher=batcher,
+                                      trainer_factory=lambda _w0: shared)
     logical = sum(r.dispatches + r.fallback_dispatches for r in bat)
-    # the profiler saw every physical program launch, and batching won
-    assert prof.dispatches == batcher.physical_dispatches
+    progs = shared._epoch_programs.values()
+    physical = sum(p.dispatches + p.fallback_dispatches
+                   + p.batched_dispatches for p in progs)
+    # the programs saw every physical program launch, and batching won
+    assert physical == batcher.physical_dispatches
     assert batcher.physical_dispatches < logical
     assert batcher.max_group >= 32
 
