@@ -7,6 +7,7 @@ a time may load the TPU library, so describing it at import would break
 every other test worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,6 +127,22 @@ def _epoch_args(sharding):
             _sds((n,), f32, sharding))
 
 
+def _assert_xla_convolutions(hlo: str):
+    """The CNN's 3x3 convs are XLA convolutions (``models/cnn._conv``'s
+    TPU branch): no nine-tap im2col patches, no branch left at run time."""
+    assert any(" convolution(" in line and "window={size=3x3" in line
+               for line in hlo.splitlines())
+    assert not re.search(r"\[[0-9,]*,9,(1|16)\]", hlo)  # the 9-tap axis
+    assert "[64,32,28,28,1,1]" not in hlo  # conv1's shifted slices
+    assert " conditional(" not in hlo
+
+
+def test_fused_epoch_program_runs_xla_convolutions(one_chip):
+    prog = _paper_program()
+    _assert_xla_convolutions(
+        prog._step.lower(*_epoch_args(one_chip)).compile().as_text())
+
+
 def test_fused_epoch_program_compiles_with_fed_agg(one_chip, monkeypatch):
     # the kernel picks interpret mode from the attached platform (CPU
     # here); the described chip needs the compiled kernel
@@ -143,3 +160,4 @@ def test_fused_epoch_program_compiles_on_4_chip_mesh(topo):
         *_epoch_args(NamedSharding(mesh, P()))).compile()
     text = compiled.as_text()
     assert "all-reduce" in text                 # the bank contraction psum
+    _assert_xla_convolutions(text)
