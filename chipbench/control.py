@@ -46,7 +46,7 @@ def program_reading(cell, seed):
     cfg, spec = cell.config, cell.mix["spec"]
     bench = harness.Bench(cell, seed).build()
     rs, iters = bench.seed, bench.local_iters
-    ref = reference.lap(cfg, spec, rs, local_iters=iters)
+    ref = reference.lap(cfg, spec, rs, local_iters=iters, root=cell.root)
     bench.lap(max_commits=len(ref.commits))
     prog = check.host_capture(bench.capture)
     del bench
@@ -86,7 +86,7 @@ def control_readings(cell, ref, rs, *, local_iters):
     from chipbench import check, reference
     cfg, spec = cell.config, cell.mix["spec"]
     N = cfg["constellation"]["sats_per_orbit"]
-    rule = reference.load_rule(spec["agg_mode"])
+    rule = reference.load_rule(spec["agg_mode"], cell.root)
     first = ref.commits[0]
     used = list(ref.used)
     out = {}
@@ -95,9 +95,11 @@ def control_readings(cell, ref, rs, *, local_iters):
         out[name] = check.compare(check.control_capture(fake, N), ref, N)
 
     held("control:bf16-training", reference.lap(
-        cfg, spec, rs, local_iters=local_iters, precision="bfloat16"))
+        cfg, spec, rs, local_iters=local_iters, precision="bfloat16",
+        root=cell.root))
     held("control:high-server", reference.lap(
-        cfg, spec, rs, local_iters=local_iters, server_precision="high"))
+        cfg, spec, rs, local_iters=local_iters, server_precision="high",
+        root=cell.root))
     half = used[:max(1, len(used) // 2)]
     hw = np.array([first.weights[s] for s in half])
     hw = hw / hw.sum() * (1.0 - first.base_weight)
@@ -167,7 +169,8 @@ def main(argv=None) -> int:
         if i < args.controls:
             t0 = time.perf_counter()
             for name, n in control_readings(
-                    cell, ref, harness.run_seed(seed, cell.config),
+                    cell, ref, harness.run_seed(seed, cell.config,
+                                                cell.root),
                     local_iters=cell.config["local_iters"]).items():
                 print(json.dumps({"reading": name, "seed": seed, **n}),
                       flush=True)

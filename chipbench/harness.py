@@ -6,15 +6,24 @@ is a data file found by its name:
 
     chipbench/configs/<config>.json   the deployment and local model
     chipbench/mixes/<traffic>.json    the strategy and the accuracy target
+    chipbench/models/<model_kind>.py  the local model: its plain reference
+                                      and its build with the program
     chipbench/rules/<agg_mode>.py     the reference's commit rule
     chipbench/metrics/<metric>.py     one reader per per-layer metric
     chipbench/flops/<model_kind>.py   forward FLOPs per training sample
 
+All of them are found under one root, the checkout the cell came from
+(``load_cell``'s ``root``, kept as ``Cell.root``; the repository's by
+default), so a test can run a cell whose files exist only elsewhere.
+
 Set-up builds the run from those files alone, with the program's public
-constructors: the Walker constellation, the seeded data and its partition,
-the image-classifier pool, the evaluator, the link and the timing of the
-simulation, and the strategy named by the mix with the mix's ``spec``
-fields put over it.
+constructors: the Walker constellation, the link and the timing of the
+simulation, the strategy named by the mix with the mix's ``spec`` fields
+put over it, and what the model kind's ``build(cfg, seed, local_iters)``
+returns: the pool that trains the local model on the seeded data, the
+evaluator and the initial model ``w0`` (host arrays).  A kind's ``build``
+imports the program inside its body only; its reference part
+(``reference.py`` lists it) imports nothing of the program.
 
 A *lap* is one whole simulation of the cell's scenario from ``w0`` to the
 configuration's horizon: ``FLSimulation.run`` -> ``EventDrivenRuntime.run``
@@ -40,7 +49,6 @@ weights, gamma, stale groups and new global model.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import itertools
 import json
 import os
@@ -50,9 +58,8 @@ from typing import Dict, List
 import numpy as np
 
 from chipbench import reference
+from chipbench.reference import ROOT, load_module  # noqa: F401 (tests)
 
-BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(BENCH_DIR)
 OUT_DIR = os.path.join(ROOT, ".chipbench_out")
 
 # the program folds its seed into uint32 arithmetic (sim seed * 1000 +
@@ -77,36 +84,28 @@ class NoChip(RuntimeError):
     """JAX found no accelerator of a known kind, or too few of them."""
 
 
-def run_seed(seed: int, config: Dict) -> int:
+def run_seed(seed: int, config: Dict, root: str = ROOT) -> int:
     """The seed handed to the program and the reference, drawn from the
     driver's ``--seed`` (any whole number, also past 32 bits).
 
-    The seed draws the labels, and the labels set how many samples every
-    satellite trains on (its shard, cut to the smallest); that count is a
-    shape of the compiled programs.  So the first candidate whose shards
-    hold the configuration's ``shard_rows`` is taken: every seed runs the
-    same shapes and compiles nothing new, with other data."""
+    The seed draws the data, and the data set how many samples every
+    satellite trains on (its shard, cut to the smallest; the model kind's
+    ``shard_rows``); that count is a shape of the compiled programs.  So
+    the first candidate whose shards hold the configuration's
+    ``shard_rows`` is taken: every seed runs the same shapes and compiles
+    nothing new, with other data."""
+    kind = reference.load_kind(config["model_kind"], root)
     for k in itertools.count():
         entropy = [int(seed) % 2 ** 64, k]
         state = np.random.SeedSequence(entropy).generate_state(1, np.uint32)
         cand = int(state[0]) % _SEED_RANGE
-        if reference.shard_rows(cand, config) == config["shard_rows"]:
+        if kind.shard_rows(cand, config) == config["shard_rows"]:
             return cand
 
 
 def _load_json(path: str):
     with open(path) as f:
         return json.load(f)
-
-
-def load_module(path: str, name: str):
-    """Import a reader or counter file by its path."""
-    spec = importlib.util.spec_from_file_location(name, path)
-    if spec is None or spec.loader is None:
-        raise FileNotFoundError(path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @dataclasses.dataclass
@@ -117,6 +116,7 @@ class Cell:
     mix: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    root: str = ROOT             # where its files were found
 
     @property
     def blocking(self) -> bool:
@@ -150,7 +150,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     if missing:
         raise ValueError(f"mix {w['traffic']}: spec lacks {sorted(missing)}")
     return Cell(name, int(w["chips"]), config, mix, bench["end_to_end"],
-                bench["per_layer"])
+                bench["per_layer"], root)
 
 
 def load_peaks(root: str = ROOT) -> Dict:
@@ -173,10 +173,8 @@ def check_device(chips: int, peaks: Dict):
 
 
 def forward_flops(config: Dict, root: str = ROOT) -> int:
-    mod = load_module(os.path.join(root, "chipbench", "flops",
-                                   config["model_kind"] + ".py"),
-                      "chipbench_flops_" + config["model_kind"])
-    return int(mod.forward_flops(config))
+    return int(reference.found("flops", config["model_kind"], root)
+               .forward_flops(config))
 
 
 def _span(name: str):
@@ -230,7 +228,8 @@ class Bench:
 
     def __init__(self, cell: Cell, seed: int):
         self.cell = cell
-        self.seed = run_seed(seed, cell.config)
+        self.seed = run_seed(seed, cell.config, cell.root)
+        self.kind = reference.load_kind(cell.config["model_kind"], cell.root)
         self.local_iters = int(cell.config["local_iters"])
         self.capture = Capture()
         self._capture_next = False
@@ -255,22 +254,13 @@ class Bench:
     def build(self):
         """The run as the configuration and mix files state it."""
         self.t_build = time.perf_counter()
-        import jax
-        from repro.configs.paper_models import SmallNetConfig
         from repro.core import FLSimulation, SimConfig
         from repro.core.constellation import WalkerDelta
         from repro.core.epoch_step import make_epoch_program
         from repro.core.links import LinkModel
-        from repro.data import (class_conditional_images,
-                                paper_noniid_partition)
-        from repro.fl import Evaluator, ImageClassifierPool, get_strategy
-        from repro.models import cnn
+        from repro.fl import get_strategy
 
         cfg, mix, seed = self.cell.config, self.cell.mix, self.seed
-        model = SmallNetConfig(cfg["name"], cfg["model_kind"],
-                               cfg["image_size"], cfg["channels"],
-                               cfg["num_classes"], cfg["hidden"],
-                               tuple(cfg["conv_channels"]))
         self.spec = dataclasses.replace(get_strategy(mix["strategy"]),
                                         **mix["spec"])
         self.simcfg = SimConfig(
@@ -284,31 +274,15 @@ class Bench:
         with _span(SPAN_PREFIX + "build"):
             t0 = time.perf_counter()
             const = WalkerDelta(**cfg["constellation"])
-
-            def data(s, n):
-                return class_conditional_images(
-                    s, n, num_classes=cfg["num_classes"],
-                    size=cfg["image_size"], channels=cfg["channels"],
-                    separation=cfg["separation"])
-            imgs, labs = data(seed, cfg["train_samples"])
-            # the test set's seed is the launcher's: the run seed + 99
-            ti, tl = data(seed + 99, cfg["test_samples"])
-            if cfg["partition"] != "paper_noniid":
-                raise ValueError(f"partition {cfg['partition']!r}")
-            shards = paper_noniid_partition(labs, const.orbit_ids(), seed)
-            pool = ImageClassifierPool(model, imgs, labs, shards,
-                                       local_iters=self.local_iters,
-                                       batch_size=int(cfg["batch_size"]),
-                                       lr=float(cfg["lr"]))
-            self.w0 = jax.device_get(cnn.init_params(
-                jax.random.PRNGKey(seed), model))
-            fls = FLSimulation(self.spec, pool, Evaluator(model, ti, tl),
-                               self.simcfg, const)
+            pool, evaluator, self.w0 = self.kind.build(cfg, seed,
+                                                       self.local_iters)
+            fls = FLSimulation(self.spec, pool, evaluator, self.simcfg,
+                               const)
             self.build_s = time.perf_counter() - t0
         self.pool = pool
         self.evaluator = _Evaluator(fls.evaluator)
         self.constellation = const
-        self.fwd_flops = forward_flops(cfg)
+        self.fwd_flops = forward_flops(cfg, self.cell.root)
         self.pool.epoch_inputs = _spanned(self.pool.epoch_inputs, SPAN_GATHER)
         self.prog = make_epoch_program(self.pool, self.w0, mesh=None,
                                        use_kernel=self.spec.use_agg_kernel)

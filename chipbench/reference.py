@@ -6,19 +6,37 @@ deployment: constellation, parameter-server coordinates, link, data,
 training) and the mix file (the strategy's ``spec``: which parameter
 server, which commit rule).  In straightforward numpy and ``jax.numpy``:
 
-* the data: seeded class-conditional images and the configuration's
-  partition (the same generator rules the launcher uses);
-* the initial model: the CNN's fan-in truncated-normal initialisation;
+* the local model, found by the configuration's ``model_kind`` as
+  ``chipbench/models/<model_kind>.py`` (its reference part): the seeded
+  data and each satellite's shard, the initial model, and local training
+  of every participant of a round from the reference's own global model;
 * each round's schedule: Walker-delta orbits, an Earth-fixed parameter
   server, elevation gating on the ``dt_s`` grid, the link delay, the
   downlink with intra-orbit ISL relay and the uplink (Alg. 1);
-* local training of every participant of a round from the reference's own
-  global model: ``local_iters`` SGD steps on minibatches drawn from the
-  participant's key (paper eq. 3);
 * the commits: the rule ``chipbench/rules/<agg_mode>.py`` says when a round
   commits and with which weights; carried models re-enter later commits
   with the epoch they trained from, a satellite counts once (its newest
   model), and orbits are grouped by distance (rules that group).
+
+A model kind's reference part provides, in numpy and ``jax.numpy`` alone:
+
+``data(seed, cfg) -> (inputs, shards)``
+    ``inputs``: a tuple of host arrays indexed by sample on axis 0;
+    ``shards``: one index array per satellite.
+``shard_rows(seed, cfg) -> int``
+    the samples per satellite once shards are cut to the smallest (a shape
+    of the program's compiled step; ``harness.run_seed`` matches it).
+``init_params(seed, cfg) -> dict``
+    the initial model, a dict of leaves.
+``train_participants(w, inputs_sel, ids, epoch_seed, cfg, local_iters,
+precision) -> (tree, losses)``
+    every participant's local training from ``w``: ``inputs_sel`` is
+    ``inputs`` gathered by participant (leading axis), ``tree`` the trained
+    leaves with a leading participant axis; ``precision`` is
+    ``"highest"`` (the reference) or ``"bfloat16"`` (the control).
+
+Every file found by name (kinds, rules) is looked up under a ``root``: the
+checkout the cell came from, the repository's by default.
 
 The schedule (when each commit falls, which models it takes and from
 which epoch) is followed over the whole lap; it needs no tensor.  The models
@@ -36,7 +54,6 @@ from __future__ import annotations
 
 import dataclasses
 import importlib.util
-import math
 import os
 from typing import Dict, List, Tuple
 
@@ -48,175 +65,42 @@ OMEGA_EARTH = 7.2921159e-5
 C_LIGHT = 299_792_458.0
 MIN_COMMITS = 3
 MAX_COMMITS = 16
-RULES_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "rules")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def load_rule(agg_mode: str):
-    """The commit rule ``rules/<agg_mode>.py``."""
-    path = os.path.join(RULES_DIR, agg_mode + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_rule_" + agg_mode, path)
-    if spec is None or not os.path.isfile(path):
-        raise ValueError(f"no reference commit rule {path}")
+def load_module(path: str, name: str):
+    """Import a file by its path, as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-# ---- data -------------------------------------------------------------------
-
-def _prototypes(rng, num_classes, size, channels, smooth=3):
-    protos = rng.standard_normal((num_classes, size, size, channels))
-    for _ in range(smooth):
-        protos = (protos
-                  + np.roll(protos, 1, 1) + np.roll(protos, -1, 1)
-                  + np.roll(protos, 1, 2) + np.roll(protos, -1, 2)) / 5.0
-    protos -= protos.mean(axis=(1, 2, 3), keepdims=True)
-    protos /= protos.std(axis=(1, 2, 3), keepdims=True) + 1e-9
-    return protos
+def found(kind: str, name: str, root: str = ROOT):
+    """The file ``chipbench/<kind>/<name>.py`` under ``root``, imported."""
+    path = os.path.join(root, "chipbench", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"no {kind} file {path}")
+    return load_module(path, f"chipbench_{kind}_" + name.replace(".", "_"))
 
 
-def images(seed: int, n: int, size: int, channels: int, separation: float,
-           num_classes: int = 10):
-    """Seeded class-conditional images in [0, 1] and their labels."""
-    rng = np.random.default_rng(seed)
-    protos = _prototypes(np.random.default_rng(1234), num_classes, size,
-                         channels)
-    labels = rng.integers(0, num_classes, size=n)
-    noise = rng.standard_normal((n, size, size, channels))
-    noise = (noise + np.roll(noise, 1, 1) + np.roll(noise, 1, 2)) / 3.0
-    x = separation * protos[labels] + noise
-    x = (x - x.min()) / (x.max() - x.min() + 1e-9)
-    return x.astype(np.float32), labels.astype(np.int32)
+def load_rule(agg_mode: str, root: str = ROOT):
+    """The commit rule ``rules/<agg_mode>.py``."""
+    return found("rules", agg_mode, root)
 
 
-def shards_of(labels, cfg: Dict, seed: int) -> List[np.ndarray]:
-    """The paper's partition of the training set over satellites: those of
-    the first two orbits hold classes 0-3, the others classes 4-9, each
-    class pool dealt out in order."""
-    if cfg["partition"] != "paper_noniid":
-        raise ValueError(f"partition {cfg['partition']!r}")
-    c = cfg["constellation"]
-    N = c["sats_per_orbit"]
-    orbit_of_sat = np.arange(c["num_orbits"] * N) // N
-    rng = np.random.default_rng(seed)
-    low = np.flatnonzero(labels < 4)
-    high = np.flatnonzero(labels >= 4)
-    rng.shuffle(low)
-    rng.shuffle(high)
-    out = [None] * len(orbit_of_sat)
-    for sats, pool in ((np.flatnonzero(orbit_of_sat < 2), low),
-                       (np.flatnonzero(orbit_of_sat >= 2), high)):
-        for s, chunk in zip(sats, np.array_split(pool, len(sats))):
-            out[int(s)] = np.sort(chunk)
-    return out
-
-
-def shard_rows(seed: int, cfg: Dict) -> int:
-    """Samples per satellite once shards are cut to the smallest."""
-    labels = np.random.default_rng(seed).integers(
-        0, cfg["num_classes"], size=cfg["train_samples"])
-    return min(len(s) for s in shards_of(labels, cfg, seed))
-
-
-# ---- model ------------------------------------------------------------------
-
-def init_params(seed: int, cfg: Dict):
-    import jax
-    import jax.numpy as jnp
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    c1, c2 = cfg["conv_channels"]
-    ch, hid, ncls = cfg["channels"], cfg["hidden"], cfg["num_classes"]
-    flat = (cfg["image_size"] // 4) ** 2 * c2
-
-    def tn(k, shape, fan_in):
-        std = 1.0 / math.sqrt(fan_in)
-        return jax.random.truncated_normal(k, -2.0, 2.0, shape) * std
-
-    return {"conv1": tn(ks[0], (3, 3, ch, c1), 9 * ch),
-            "bc1": jnp.zeros((c1,)),
-            "conv2": tn(ks[1], (3, 3, c1, c2), 9 * c1),
-            "bc2": jnp.zeros((c2,)),
-            "w1": tn(ks[2], (flat, hid), flat),
-            "b1": jnp.zeros((hid,)),
-            "w2": tn(ks[3], (hid, ncls), hid),
-            "b2": jnp.zeros((ncls,))}
+def load_kind(model_kind: str, root: str = ROOT):
+    """The local model ``models/<model_kind>.py``."""
+    return found("models", model_kind, root)
 
 
 def _precision(precision: str):
+    """The server contractions' precision."""
     import jax
     return {"highest": jax.lax.Precision.HIGHEST,
-            "high": jax.lax.Precision.HIGH,
-            "default": jax.lax.Precision.DEFAULT,
-            "bfloat16": jax.lax.Precision.DEFAULT}[precision]
-
-
-def logits(params, x, precision: str = "highest"):
-    """Conv 3x3 SAME + ReLU + 2x2 max-pool, twice, then two dense layers."""
-    import jax
-    import jax.numpy as jnp
-    p = _precision(precision)
-
-    def conv(x, w, b):
-        y = jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=p)
-        return jax.nn.relu(y + b)
-
-    def pool(x):
-        return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                     (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-
-    x = pool(conv(x, params["conv1"], params["bc1"]))
-    x = pool(conv(x, params["conv2"], params["bc2"]))
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, params["w1"], precision=p) + params["b1"])
-    return jnp.dot(x, params["w2"], precision=p) + params["b2"]
-
-
-def xent(params, x, y, precision: str = "highest"):
-    import jax
-    import jax.numpy as jnp
-    z = logits(params, x, precision).astype(jnp.float32)
-    gold = jnp.take_along_axis(z, y[:, None], axis=-1)[:, 0]
-    return (jax.nn.logsumexp(z, axis=-1) - gold).mean()
-
-
-def train_participants(w, imgs, labs, ids, epoch_seed: int, cfg: Dict,
-                       local_iters: int, precision: str = "highest"):
-    """Every participant's ``local_iters`` SGD steps from ``w``: rows of
-    trained parameters (a pytree with a leading participant axis) and each
-    participant's mean step loss.  Participant ``i`` trains on
-    ``imgs[i], labs[i]`` with key ``PRNGKey(epoch_seed * 9973 + ids[i])``
-    (uint32 arithmetic), drawing ``batch_size`` samples per step."""
-    import jax
-    import jax.numpy as jnp
-    dtype = jnp.bfloat16 if precision == "bfloat16" else jnp.float32
-    lr, bs = cfg["lr"], cfg["batch_size"]
-
-    def one(w, x, y, sid, epoch_seed):
-        key = jax.random.PRNGKey(epoch_seed * jnp.uint32(9973)
-                                 + sid.astype(jnp.uint32))
-        n = x.shape[0]
-        params = jax.tree.map(lambda a: a.astype(dtype), w)
-        x = x.astype(dtype)
-
-        def step(params, k):
-            idx = jax.random.randint(k, (bs,), 0, n)
-            loss, g = jax.value_and_grad(xent)(params, x[idx], y[idx],
-                                               precision)
-            return jax.tree.map(lambda a, b: a - lr * b, params, g), loss
-
-        params, losses = jax.lax.scan(step, params,
-                                      jax.random.split(key, local_iters))
-        return (jax.tree.map(lambda a: a.astype(jnp.float32), params),
-                losses.astype(jnp.float32).mean())
-
-    # w and the seed are arguments, not constants, so one compiled program
-    # (and its persistent-cache entry) serves every round and seed
-    fn = jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0, None)))
-    return fn(w, jnp.asarray(imgs), jnp.asarray(labs),
-              jnp.asarray(ids, jnp.int32), jnp.uint32(epoch_seed))
+            "high": jax.lax.Precision.HIGH}[precision]
 
 
 def flatten(tree) -> np.ndarray:
@@ -510,12 +394,12 @@ def _geometry(cfg: Dict, spec: Dict) -> Geometry:
                     cfg["dt_s"])
 
 
-def schedule(cfg: Dict, spec: Dict, bits: float) -> Tuple[List[Slot],
-                                                           List[List[int]]]:
+def schedule(cfg: Dict, spec: Dict, bits: float, root: str = ROOT
+             ) -> Tuple[List[Slot], List[List[int]]]:
     """Every commit of a lap, and each round's participants: one round in
     flight, opened at the previous round's last commit; models carried
     past a commit re-enter the first commit after they land."""
-    rule = load_rule(spec["agg_mode"])
+    rule = load_rule(spec["agg_mode"], root)
     geo = _geometry(cfg, spec)
     links = Links(geo, bits, cfg)
     t, beta = 0.0, 0
@@ -548,23 +432,23 @@ def schedule(cfg: Dict, spec: Dict, bits: float) -> Tuple[List[Slot],
 
 
 def lap(cfg: Dict, spec: Dict, seed: int, *, local_iters: int,
-        precision: str = "highest", server_precision: str = "highest"
-        ) -> Lap:
+        precision: str = "highest", server_precision: str = "highest",
+        root: str = ROOT) -> Lap:
     """The reference's lap for ``seed`` (a run seed) under the mix's
     ``spec`` (``ps_scenario``, ``agg_mode``, ``use_isl``, ``num_groups``):
     its whole schedule, and its models, weights and global model for the
-    first commits, up to the first that holds a stale model."""
+    first commits, up to the first that holds a stale model.  The model
+    kind and the commit rule are found under ``root``."""
     import jax
-    rule = load_rule(spec["agg_mode"])
+    kind = load_kind(cfg["model_kind"], root)
+    rule = load_rule(spec["agg_mode"], root)
     N = cfg["constellation"]["sats_per_orbit"]
-    x, y = images(seed, cfg["train_samples"], cfg["image_size"],
-                  cfg["channels"], cfg["separation"])
-    shards = shards_of(y, cfg, seed)
+    inputs, shards = kind.data(seed, cfg)
     sizes = {s: len(sh) for s, sh in enumerate(shards)}
     m = min(sizes.values())
-    w0 = {k: np.asarray(v) for k, v in init_params(seed, cfg).items()}
+    w0 = {k: np.asarray(v) for k, v in kind.init_params(seed, cfg).items()}
     w0_flat = flatten(w0)
-    slots, rounds = schedule(cfg, spec, 32.0 * w0_flat.size)
+    slots, rounds = schedule(cfg, spec, 32.0 * w0_flat.size, root)
     if len(slots) < MIN_COMMITS:
         raise ValueError(f"the lap has {len(slots)} commits, fewer than "
                          f"the {MIN_COMMITS} the check follows")
@@ -583,9 +467,10 @@ def lap(cfg: Dict, spec: Dict, seed: int, *, local_iters: int,
         if r >= 0:
             ps = rounds[r]
             sel = np.stack([shards[s][:m] for s in ps])
-            tree, _loss = train_participants(
-                unflatten(w, w0), x[sel], y[sel], np.asarray(ps),
-                seed * 1000 + beta, cfg, local_iters, precision)
+            tree, _loss = kind.train_participants(
+                unflatten(w, w0), tuple(a[sel] for a in inputs),
+                np.asarray(ps), seed * 1000 + beta, cfg, local_iters,
+                precision)
             tree = jax.tree.map(np.asarray, tree)
             rows[r] = np.stack([flatten({k: v[i] for k, v in tree.items()})
                                 for i in range(len(ps))])

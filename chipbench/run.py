@@ -62,7 +62,7 @@ def reader_context(bench, peak, trace):
 def measure(bench, seconds: float, trace: bool, dev, peak):
     """The window of a warmed run and its metrics.  Returns the result
     without ``correct`` and the lines for standard error."""
-    from chipbench import harness, trace_reduce
+    from chipbench import harness, reference, trace_reduce
 
     trace_dir = os.path.join(harness.OUT_DIR, "trace", bench.cell.name)
     if trace:
@@ -91,10 +91,7 @@ def measure(bench, seconds: float, trace: bool, dev, peak):
         tr = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
         ctx = reader_context(bench, peak, tr)
         for m in cell.metrics("per_layer"):
-            mod = harness.load_module(
-                os.path.join(harness.BENCH_DIR, "metrics", m["name"] + ".py"),
-                "chipbench_metric_" + m["name"].replace(".", "_"))
-            v = mod.read(ctx)
+            v = reference.found("metrics", m["name"], cell.root).read(ctx)
             if v is not None:
                 result["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}
         extra = {"busy_s": tr["busy_s"], "window_s": tr["window_s"]}
@@ -121,7 +118,7 @@ def verify(cell, prog, seed: int, local_iters: int, ref=None):
     t0 = time.perf_counter()
     if ref is None:
         ref = reference.lap(cfg, cell.mix["spec"], seed,
-                            local_iters=local_iters)
+                            local_iters=local_iters, root=cell.root)
     nums = check.compare(prog, ref, cfg["constellation"]["sats_per_orbit"])
     ok, lines = check.judge(nums, cfg["limits"])
     lines.insert(0, f"reference check {time.perf_counter() - t0!r} s, "
