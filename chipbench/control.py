@@ -19,7 +19,12 @@ In one process, on the chip and at the cell's own size:
   (``fault:altered-schedule``); the commit returning the global model
   unchanged (``fault:unchanged``); stale models weighted as fresh
   (``fault:stale-undiscounted``); carried models never re-entering
-  (``fault:carried-dropped``).
+  (``fault:carried-dropped``); the first commit's first participant
+  trained on the shard of the satellite at the far end of the
+  constellation's numbering (``fault:wrong-shard``).
+
+Each program reading also gives the reference's peak host memory
+(``reference_peak_bytes``: numpy's allocations, by ``tracemalloc``).
 
 Prints one JSON line per reading.
 """
@@ -31,6 +36,7 @@ import json
 import os
 import sys
 import time
+import tracemalloc
 
 import numpy as np
 
@@ -46,19 +52,21 @@ def program_reading(cell, seed):
     cfg, spec = cell.config, cell.mix["spec"]
     bench = harness.Bench(cell, seed).build()
     rs, iters = bench.seed, bench.local_iters
+    tracemalloc.start()
     ref = reference.lap(cfg, spec, rs, local_iters=iters, root=cell.root)
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
     bench.lap(max_commits=len(ref.commits))
-    prog = check.host_capture(bench.capture)
+    prog = check.host_capture(bench.capture, ref.w0)
     del bench
     part = dataclasses.replace(ref, schedule=ref.schedule[:len(ref.commits)])
-    return check.compare(prog, part,
-                         cfg["constellation"]["sats_per_orbit"]), ref
+    nums = check.compare(prog, part, cfg["constellation"]["sats_per_orbit"])
+    return dict(nums, reference_peak_bytes=peak), ref
 
 
-def _recommit(ref, cfg, k, models, weights, base_weight,
-              precision="highest"):
-    """``ref`` with commit ``k`` aggregating ``models`` (rows of the first
-    round, by satellite) with ``weights``; the global model after it
+def _recommit(ref, cfg, models, weights, base_weight):
+    """``ref`` with its first commit aggregating ``models`` (rows of the
+    first round, by satellite) with ``weights``; the global model after it
     follows from those rows."""
     from chipbench import reference
     N = cfg["constellation"]["sats_per_orbit"]
@@ -67,16 +75,16 @@ def _recommit(ref, cfg, k, models, weights, base_weight,
                                         for n, v in ref.rows.items()})
                      for s in models])
     w0 = reference.flatten(ref.w0)
-    prev = w0 if k == 0 else ref.commits[k - 1].w
-    w = reference.contract(weights, flat, base_weight, prev, precision)
+    w = reference.contract(weights, flat, base_weight, w0, "highest")
     commits = list(ref.commits)
-    commits[k] = dataclasses.replace(
-        commits[k], w=w, base_weight=float(base_weight),
+    commits[0] = dataclasses.replace(
+        commits[0], w=w, base_weight=float(base_weight),
+        change=reference.change_norms(w, w0, reference.leaf_slices(ref.w0)),
         weights={s: float(v) for s, v in zip(models, weights)})
     dists = ref.dists
-    if k == 0 and ref.dists:
+    if ref.dists:
         dists = reference.orbit_distances(list(models), flat, ref.sizes, w0,
-                                          N, precision)
+                                          N)
     return dataclasses.replace(ref, commits=commits, dists=dists)
 
 
@@ -103,16 +111,18 @@ def control_readings(cell, ref, rs, *, local_iters):
     half = used[:max(1, len(used) // 2)]
     hw = np.array([first.weights[s] for s in half])
     hw = hw / hw.sum() * (1.0 - first.base_weight)
-    held("fault:half-batch", _recommit(ref, cfg, 0, half, hw,
+    held("fault:half-batch", _recommit(ref, cfg, half, hw,
                                        first.base_weight))
 
     rows = {k: np.array(v, copy=True) for k, v in ref.rows.items()}
     i = ref.participants.index(used[0])
     for k, v in rows.items():
         v[i] = ref.w0[k] + 2.0 * (v[i] - ref.w0[k])
-    altered = dataclasses.replace(ref, rows=rows)
+    blocks = reference.first_blocks(rows, ref.participants, used, ref.sizes,
+                                    N, rule.BLOCKS == "orbit")
+    altered = dataclasses.replace(ref, rows=rows, blocks=blocks)
     held("fault:altered-row", _recommit(
-        altered, cfg, 0, used, [first.weights[s] for s in used],
+        altered, cfg, used, [first.weights[s] for s in used],
         first.base_weight))
 
     sl = ref.schedule[0]
@@ -122,7 +132,10 @@ def control_readings(cell, ref, rs, *, local_iters):
         ref, schedule=[late] + ref.schedule[1:]))
 
     commits = list(ref.commits)
-    commits[0] = dataclasses.replace(commits[0], w=reference.flatten(ref.w0))
+    w0 = reference.flatten(ref.w0)
+    commits[0] = dataclasses.replace(
+        commits[0], w=w0,
+        change=reference.change_norms(w0, w0, reference.leaf_slices(ref.w0)))
     held("fault:unchanged", dataclasses.replace(ref, commits=commits))
 
     commits = list(ref.commits)
@@ -141,6 +154,12 @@ def control_readings(cell, ref, rs, *, local_iters):
                                              if m[2] >= k])
              for k, sl in enumerate(ref.schedule)]
     held("fault:carried-dropped", dataclasses.replace(ref, schedule=slots))
+
+    S = len(ref.sizes)
+    far = S - 1 if used[0] < S // 2 else 0
+    held("fault:wrong-shard", reference.lap(
+        cfg, spec, rs, local_iters=local_iters, root=cell.root,
+        shard_of={used[0]: far}))
     return out
 
 

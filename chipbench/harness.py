@@ -43,8 +43,14 @@ The harness wraps instances, never the program's source: host spans
 contact-plan queries, the input gather, the fused dispatch and the
 evaluator's reads.  The first commits of every lap are captured for the
 comparison with the plain reference (``reference.py``, ``check.py``): the
-first commit's trained rows and distances, and each commit's time, models,
-weights, gamma, stale groups and new global model.
+first commit's step arguments and outputs (its bank or block sums, and
+distances; held on the device, read only after the window), and each
+commit's time, models, weights, gamma, stale groups and new global model.
+
+A cell whose ``chips`` is more than one runs on a mesh over that many
+devices, one axis named ``"data"`` (the axis the program shards over),
+handed to ``make_epoch_program`` and ``SimConfig``.  A one-chip cell
+passes ``mesh=None``.
 """
 from __future__ import annotations
 
@@ -57,7 +63,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from chipbench import reference
+from chipbench import check, reference
 from chipbench.reference import ROOT, load_module  # noqa: F401 (tests)
 
 OUT_DIR = os.path.join(ROOT, ".chipbench_out")
@@ -213,7 +219,8 @@ class CompileCounter:
 class Capture:
     """What a lap's first commits produced, for the reference check."""
     ids: object = None           # padded participant ids (device)
-    stack: object = None         # trained bank (C, N) (device)
+    wv_bank: object = None       # their aggregation weights (device)
+    stack: object = None         # bank (C, N) or block sums (B, N) (device)
     dists: object = None         # new-orbit distances (device)
     dw_row: object = None
     dw_seg: object = None
@@ -221,6 +228,10 @@ class Capture:
     blocked_m: int = 0
     participants: tuple = ()
     commits: list = dataclasses.field(default_factory=list)
+    form: str = "bank"           # the configuration's step_output
+    per_orbit: int = 1
+    by_orbit: bool = True        # the commit rule's BLOCKS is "orbit"
+    size_of: object = None       # the program's data size of a satellite
 
 
 class Bench:
@@ -231,6 +242,13 @@ class Bench:
         self.seed = run_seed(seed, cell.config, cell.root)
         self.kind = reference.load_kind(cell.config["model_kind"], cell.root)
         self.local_iters = int(cell.config["local_iters"])
+        form = cell.config.get("step_output", "bank")
+        if form not in check.FORMS:
+            raise ValueError(f"step_output {form!r}: one of {check.FORMS}")
+        rule = reference.load_rule(cell.mix["spec"]["agg_mode"], cell.root)
+        self.capture_layout = dict(
+            form=form, by_orbit=rule.BLOCKS == "orbit",
+            per_orbit=int(cell.config["constellation"]["sats_per_orbit"]))
         self.capture = Capture()
         self._capture_next = False
         self._weights = None
@@ -261,6 +279,7 @@ class Bench:
         from repro.fl import get_strategy
 
         cfg, mix, seed = self.cell.config, self.cell.mix, self.seed
+        self.mesh = make_mesh(self.cell.chips)
         self.spec = dataclasses.replace(get_strategy(mix["strategy"]),
                                         **mix["spec"])
         self.simcfg = SimConfig(
@@ -268,7 +287,7 @@ class Bench:
             duration_s=float(cfg["horizon_s"]), dt_s=float(cfg["dt_s"]),
             train_time_s=float(cfg["train_time_s"]),
             agg_timeout_s=float(cfg["agg_timeout_s"]),
-            min_models=int(cfg["min_models"]),
+            min_models=int(cfg["min_models"]), mesh=self.mesh,
             link=LinkModel(rate_bps=float(cfg["link_rate_bps"]),
                            proc_delay_s=float(cfg["proc_delay_s"])))
         with _span(SPAN_PREFIX + "build"):
@@ -284,7 +303,7 @@ class Bench:
         self.constellation = const
         self.fwd_flops = forward_flops(cfg, self.cell.root)
         self.pool.epoch_inputs = _spanned(self.pool.epoch_inputs, SPAN_GATHER)
-        self.prog = make_epoch_program(self.pool, self.w0, mesh=None,
+        self.prog = make_epoch_program(self.pool, self.w0, mesh=self.mesh,
                                        use_kernel=self.spec.use_agg_kernel)
         self.prog._step = _Dispatch(self.prog._step, self)
         return self
@@ -363,6 +382,17 @@ class Bench:
                 + (self.prog.fallback_dispatches - f0))
 
 
+def make_mesh(chips: int):
+    """None for one chip; else a mesh over the first ``chips`` devices,
+    one axis ``"data"`` (the program's own constructor, the axis
+    ``Auto``)."""
+    if chips <= 1:
+        return None
+    import jax
+    from repro.launch.mesh import make_mesh as program_mesh
+    return program_mesh((chips,), ("data",), devices=jax.devices()[:chips])
+
+
 def _spanned(fn, name: str):
     def wrapped(*args, **kwargs):
         with _span(name):
@@ -387,11 +417,11 @@ class _Dispatch:
             b.dispatch_s += time.perf_counter() - t0
         if b._capture_next:
             b._capture_next = False
-            (_w, _carry, _inputs, ids, _seed, _wv_bank, _wv_carry, _base_w,
+            (_w, _carry, _inputs, ids, _seed, wv_bank, _wv_carry, _base_w,
              dw_row, dw_seg, kpad, blocked_m, _dw_carry, _ref) = args
             _new_w, stack, dists, _losses = out
             c = b.capture
-            c.ids, c.stack, c.dists = ids, stack, dists
+            c.ids, c.wv_bank, c.stack, c.dists = ids, wv_bank, stack, dists
             c.dw_row, c.dw_seg = dw_row, dw_seg
             c.kpad, c.blocked_m = int(kpad), int(blocked_m)
         return out
@@ -411,7 +441,9 @@ class _Commit:
         if beta == 0:
             b._capture_next = bool(participants)
             b.capture = Capture(participants=tuple(int(s) for s in
-                                                   participants))
+                                                   participants),
+                                size_of=b.pool.data_size,
+                                **b.capture_layout)
         if participants and b.in_window:
             cfg = b.cell.config
             b.useful_flops += (3.0 * b.fwd_flops * b.local_iters

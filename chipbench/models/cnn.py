@@ -139,6 +139,10 @@ def xent(params, x, y, precision: str = "highest"):
     return (jax.nn.logsumexp(z, axis=-1) - gold).mean()
 
 
+# jitted local training by (lr, batch, local_iters, precision)
+_TRAIN: Dict = {}
+
+
 def train_participants(w, inputs_sel, ids, epoch_seed: int, cfg: Dict,
                        local_iters: int, precision: str = "highest"):
     """Every participant's ``local_iters`` SGD steps from ``w``: rows of
@@ -173,8 +177,13 @@ def train_participants(w, inputs_sel, ids, epoch_seed: int, cfg: Dict,
                 losses.astype(jnp.float32).mean())
 
     # w and the seed are arguments, not constants, so one compiled program
-    # (and its persistent-cache entry) serves every round and seed
-    fn = jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0, None)))
+    # (and its persistent-cache entry) serves every round and seed; the
+    # jitted function is kept, so a round trained in chunks traces once
+    key = (lr, bs, local_iters, precision)
+    fn = _TRAIN.get(key)
+    if fn is None:
+        fn = _TRAIN[key] = jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0,
+                                                          None)))
     return fn(w, jnp.asarray(imgs), jnp.asarray(labs),
               jnp.asarray(ids, jnp.int32), jnp.uint32(epoch_seed))
 

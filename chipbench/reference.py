@@ -33,7 +33,10 @@ precision) -> (tree, losses)``
     every participant's local training from ``w``: ``inputs_sel`` is
     ``inputs`` gathered by participant (leading axis), ``tree`` the trained
     leaves with a leading participant axis; ``precision`` is
-    ``"highest"`` (the reference) or ``"bfloat16"`` (the control).
+    ``"highest"`` (the reference) or ``"bfloat16"`` (the control).  A
+    participant's trained model depends only on ``w``, its own inputs, its
+    id and ``epoch_seed``, never on the other participants of the call: the
+    reference trains a round in chunks (``chunk_size``).
 
 Every file found by name (kinds, rules) is looked up under a ``root``: the
 checkout the cell came from, the repository's by default.
@@ -49,13 +52,27 @@ refused.
 the reference; the controls are the same code one precision step below
 what the configuration states (``"bfloat16"`` training, or ``"high"``
 server contractions).
+
+Memory stays bounded by the blocks, not the participants.  A commit's
+weights are, within one *block* of its models, in proportion to their data
+sizes (the rule's ``BLOCKS``: ``"orbit"``, the models of one orbit trained
+from one epoch; ``"model"``, each model alone), so eq. 14 and the grouping
+distances need the trained models only through their size-weighted sums,
+one per block.  Each trained chunk is folded at once, in float64, into the
+sums of the blocks its models go to (orbit or model, epoch trained from,
+the commit that takes them; a carried model's block lives until that
+commit) and dropped: O((blocks + chunk) x N) floats, a chunk being as
+many participants as ``CHUNK_BYTES`` holds (``chunk_size``).  The first
+round is also folded into the blocks the check compares (``block_key``);
+its single rows are kept only for a configuration whose program returns
+its bank (``step_output`` ``"bank"``), whose check compares them.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import os
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -65,6 +82,9 @@ OMEGA_EARTH = 7.2921159e-5
 C_LIGHT = 299_792_458.0
 MIN_COMMITS = 3
 MAX_COMMITS = 16
+# host bytes for one trained chunk: its rows as float64 flats and the
+# kind's float32 leaves, 12 bytes a parameter
+CHUNK_BYTES = 1 << 30
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -315,6 +335,15 @@ class Slot:
 
 
 @dataclasses.dataclass
+class Block:
+    """Size-weighted sum of trained models: ``sum`` = sum of size_i x_i
+    over ``sats``, float64; ``size`` = sum of size_i."""
+    sats: List[int]
+    size: float
+    sum: np.ndarray
+
+
+@dataclasses.dataclass
 class Commit:
     t_agg: float
     models: List[Tuple[int, int]]    # (sat, epoch trained from), in order
@@ -323,54 +352,120 @@ class Commit:
     gamma: float
     stale_groups: int
     groups: List[List[int]]          # indices into ``models``
-    w: np.ndarray                    # the global model after it, float64
+    change: Dict[str, float]         # per leaf: norm of (w - w0)
+    w: Optional[np.ndarray] = None   # the global model after it (float64;
+    #                                  kept for the first commit only)
 
 
 @dataclasses.dataclass
 class Lap:
     schedule: List[Slot]             # every commit of the lap
     participants: List[int]          # round 0's
-    rows: Dict                       # round 0's trained rows (P, ...)
+    rows: Optional[Dict]             # round 0's trained rows (P, ...), or
+    #                                  None where the check reads no rows
     used: List[int]                  # sat ids of the first commit, in order
     sizes: Dict[int, int]            # data size per satellite
     w0: Dict                         # host float32 leaves
     dists: Dict[int, float]          # first commit: orbit -> distance
     commits: List[Commit]            # the commits followed with models
+    blocks: Dict[tuple, Block]       # round 0's, by ``block_key``
 
 
-def contract(ws, flat_rows, base_w: float, w_flat, precision: str):
-    """eq. 14: ``base_w * w + ws @ rows``; float64 on the host for the
-    reference, the device at ``precision`` for a control."""
+def block_key(sat: int, taken: bool, per_orbit: int, by_orbit: bool):
+    """The block of a step's output that a trained model goes to: its orbit
+    (a rule with ``BLOCKS`` ``"orbit"``) or the satellite itself, and
+    whether the step's commit takes it (else it is carried, or never
+    arrives)."""
+    return (int(sat) // per_orbit if by_orbit else int(sat), bool(taken))
+
+
+def change_norms(w_flat, w0_flat, slices) -> Dict[str, float]:
+    """Per leaf, the norm of the change ``w - w0`` (float64)."""
+    return {k: float(np.linalg.norm(np.asarray(w_flat[sl], np.float64)
+                                    - w0_flat[sl]))
+            for k, sl in slices.items()}
+
+
+def _weighted_sum(wv, rows, precision: str) -> np.ndarray:
     if precision == "highest":
-        return (base_w * np.asarray(w_flat, np.float64)
-                + np.asarray(ws, np.float64) @ np.asarray(flat_rows,
-                                                          np.float64))
+        return np.asarray(wv, np.float64) @ np.asarray(rows, np.float64)
+    import jax.numpy as jnp
+    return np.asarray(jnp.dot(jnp.asarray(wv, jnp.float32),
+                              jnp.asarray(rows, jnp.float32),
+                              precision=_precision(precision)), np.float64)
+
+
+def fold(blocks: Dict, keys: List, sats: List[int], flat: np.ndarray,
+         sizes: Dict[int, int], precision: str = "highest") -> None:
+    """Add the rows ``flat`` (one per satellite of ``sats``) into
+    ``blocks``, row ``i`` under ``keys[i]`` (None: into none), each
+    weighted by its satellite's data size; float64 on the host, or the
+    device at ``precision`` for a control."""
+    for key in dict.fromkeys(k for k in keys if k is not None):
+        idx = [i for i, k in enumerate(keys) if k == key]
+        wv = np.array([float(sizes[sats[i]]) for i in idx])
+        part = _weighted_sum(wv, flat[idx], precision)
+        b = blocks.get(key)
+        if b is None:
+            blocks[key] = Block([sats[i] for i in idx], float(wv.sum()), part)
+        else:
+            b.sats += [sats[i] for i in idx]
+            b.size += float(wv.sum())
+            b.sum += part
+
+
+def first_blocks(rows: Dict, participants: List[int], used: List[int],
+                 sizes: Dict[int, int], per_orbit: int,
+                 by_orbit: bool) -> Dict[tuple, Block]:
+    """Round 0's blocks (``block_key``) from its trained rows."""
+    flat = np.stack([flatten({k: v[i] for k, v in rows.items()})
+                     for i in range(len(participants))])
+    out: Dict[tuple, Block] = {}
+    fold(out, [block_key(s, s in used, per_orbit, by_orbit)
+               for s in participants], list(participants), flat, sizes)
+    return out
+
+
+def contract(ws, rows, base_w: float, w_flat, precision: str):
+    """eq. 14: ``base_w * w + sum_i ws_i rows_i``; float64 on the host for
+    the reference, the device at ``precision`` for a control.  ``rows``
+    are trained models or block sums."""
+    if precision == "highest":
+        out = float(base_w) * np.asarray(w_flat, np.float64)
+        for c, r in zip(ws, rows):
+            out += float(c) * np.asarray(r, np.float64)
+        return out
     import jax.numpy as jnp
     out = (jnp.float32(base_w) * jnp.asarray(w_flat, jnp.float32)
            + jnp.dot(jnp.asarray(ws, jnp.float32),
-                     jnp.asarray(flat_rows, jnp.float32),
+                     jnp.asarray(np.stack(rows), jnp.float32),
                      precision=_precision(precision)))
     return np.asarray(out, np.float64)
 
 
+def block_distances(orbit_blocks, w0_flat) -> Dict[int, float]:
+    """Grouping distances: per orbit, its partial model (the size-weighted
+    mean of its models, from the blocks ``(orbit, Block)`` it holds)
+    against ``w0`` (Euclidean), in the order the orbits first appear."""
+    acc: Dict[int, list] = {}
+    for o, b in orbit_blocks:
+        if o in acc:
+            acc[o][0] = acc[o][0] + b.sum
+            acc[o][1] += b.size
+        else:
+            acc[o] = [b.sum, b.size]
+    w0 = np.asarray(w0_flat, np.float64)
+    return {o: float(np.linalg.norm(s / n - w0)) for o, (s, n) in acc.items()}
+
+
 def orbit_distances(sats, flat_rows, sizes, w0_flat, per_orbit: int,
                     precision: str = "highest") -> Dict[int, float]:
-    """Grouping distances: per orbit, the size-weighted mean of its models'
-    rows, against ``w0`` (Euclidean)."""
-    orbits = list(dict.fromkeys(s // per_orbit for s in sats))
-    wmat = np.zeros((len(orbits), len(sats)))
-    for j, s in enumerate(sats):
-        wmat[orbits.index(s // per_orbit), j] = sizes[s]
-    wmat /= wmat.sum(axis=1, keepdims=True)
-    if precision == "highest":
-        pm = wmat @ np.asarray(flat_rows, np.float64)
-    else:
-        import jax.numpy as jnp
-        pm = np.asarray(jnp.dot(jnp.asarray(wmat, jnp.float32),
-                                jnp.asarray(flat_rows, jnp.float32),
-                                precision=_precision(precision)), np.float64)
-    d = np.linalg.norm(pm - np.asarray(w0_flat, np.float64)[None, :], axis=1)
-    return {o: float(d[i]) for i, o in enumerate(orbits)}
+    """Grouping distances from single rows: each orbit's models folded into
+    one block (at ``precision``), then ``block_distances``."""
+    blocks: Dict[int, Block] = {}
+    fold(blocks, [s // per_orbit for s in sats], list(sats),
+         np.asarray(flat_rows), sizes, precision)
+    return block_distances(blocks.items(), w0_flat)
 
 
 def _newest_per_sat(models):
@@ -431,23 +526,62 @@ def schedule(cfg: Dict, spec: Dict, bits: float, root: str = ROOT
     return slots, rounds
 
 
+def initial_model(cfg: Dict, seed: int, root: str = ROOT) -> Dict:
+    """The reference's initial model for run seed ``seed``: host leaves."""
+    kind = load_kind(cfg["model_kind"], root)
+    return {k: np.asarray(v) for k, v in kind.init_params(seed, cfg).items()}
+
+
+def _train_chunk(kind, w_tree, inputs, shards, m, part, epoch_seed, cfg,
+                 local_iters, precision, keep):
+    """One chunk's local training: its rows as float64 flats, and the
+    trained leaves (host float32 copies) where ``keep``.  What the kind
+    returned is dropped on return."""
+    import jax
+    sel = np.stack([shards[s][:m] for s in part])
+    tree, _loss = kind.train_participants(
+        w_tree, tuple(a[sel] for a in inputs), np.asarray(part), epoch_seed,
+        cfg, local_iters, precision)
+    tree = jax.tree.map(np.asarray, tree)
+    flat = np.stack([flatten({k: v[i] for k, v in tree.items()})
+                     for i in range(len(part))])
+    kept = {k: np.array(v) for k, v in tree.items()} if keep else None
+    return flat, kept
+
+
+def chunk_size(n_params: int, participants: int) -> int:
+    """Participants trained at once: the whole round where its rows fit
+    ``CHUNK_BYTES``, else as many as fit, at least one."""
+    return max(1, min(participants, CHUNK_BYTES // (12 * n_params)))
+
+
 def lap(cfg: Dict, spec: Dict, seed: int, *, local_iters: int,
         precision: str = "highest", server_precision: str = "highest",
-        root: str = ROOT) -> Lap:
+        root: str = ROOT, w0: Optional[Dict] = None,
+        shard_of: Optional[Dict[int, int]] = None,
+        chunk: Optional[int] = None) -> Lap:
     """The reference's lap for ``seed`` (a run seed) under the mix's
     ``spec`` (``ps_scenario``, ``agg_mode``, ``use_isl``, ``num_groups``):
     its whole schedule, and its models, weights and global model for the
     first commits, up to the first that holds a stale model.  The model
-    kind and the commit rule are found under ``root``."""
-    import jax
+    kind and the commit rule are found under ``root``.  ``w0``: the initial
+    model where it is already made (``initial_model``).  ``shard_of``: a
+    planted fault, the satellite whose shard a participant trains on.
+    ``chunk``: participants trained at once, ``chunk_size``'s by
+    default."""
     kind = load_kind(cfg["model_kind"], root)
     rule = load_rule(spec["agg_mode"], root)
     N = cfg["constellation"]["sats_per_orbit"]
+    by_orbit = rule.BLOCKS == "orbit"
     inputs, shards = kind.data(seed, cfg)
     sizes = {s: len(sh) for s, sh in enumerate(shards)}
     m = min(sizes.values())
-    w0 = {k: np.asarray(v) for k, v in kind.init_params(seed, cfg).items()}
+    if shard_of:
+        shards = [shards[shard_of.get(s, s)] for s in range(len(shards))]
+    if w0 is None:
+        w0 = initial_model(cfg, seed, root)
     w0_flat = flatten(w0)
+    slices = leaf_slices(w0)
     slots, rounds = schedule(cfg, spec, 32.0 * w0_flat.size, root)
     if len(slots) < MIN_COMMITS:
         raise ValueError(f"the lap has {len(slots)} commits, fewer than "
@@ -457,37 +591,60 @@ def lap(cfg: Dict, spec: Dict, seed: int, *, local_iters: int,
     n = min(max(MIN_COMMITS, stale[0] + 1 if stale else 0), MAX_COMMITS,
             len(slots))
     grouping = Grouping(spec["num_groups"]) if rule.GROUPING else None
+    keep_rows = cfg.get("step_output", "bank") == "bank"
+    share = server_precision == "highest"
 
+    def group(s):
+        return s // N if by_orbit else s
+
+    # the commit that takes each trained model, among those followed
+    taker = {mm[3]: j for j, sl in enumerate(slots[:n]) for mm in sl.models}
+    used0 = [s for (_t, s, _k) in slots[0].used]
     w = w0_flat
-    rows: Dict[int, np.ndarray] = {}
-    first: Dict = {}
+    sums: Dict[tuple, Block] = {}        # (group, epoch, taker) -> Block
+    blocks: Dict[tuple, Block] = {}      # round 0's, by block_key
+    parts: List[Dict] = []
+    dists: Dict[int, float] = {}
     commits: List[Commit] = []
     for beta, sl in enumerate(slots[:n]):
         r, _b = sl.train
         if r >= 0:
             ps = rounds[r]
-            sel = np.stack([shards[s][:m] for s in ps])
-            tree, _loss = kind.train_participants(
-                unflatten(w, w0), tuple(a[sel] for a in inputs),
-                np.asarray(ps), seed * 1000 + beta, cfg, local_iters,
-                precision)
-            tree = jax.tree.map(np.asarray, tree)
-            rows[r] = np.stack([flatten({k: v[i] for k, v in tree.items()})
-                                for i in range(len(ps))])
-            if not first:
-                first = {"participants": list(ps), "rows": tree,
-                         "used": [s for (_t, s, _k) in sl.used]}
-        flat = np.stack([rows[rk[0]][rk[1]] for (_t, _s, _e, rk)
-                         in sl.models])
+            w_tree = unflatten(w, w0)
+            step = chunk or chunk_size(w0_flat.size, len(ps))
+            for a in range(0, len(ps), step):
+                part = ps[a:a + step]
+                flat, kept = _train_chunk(
+                    kind, w_tree, inputs, shards, m, part, seed * 1000 + beta,
+                    cfg, local_iters, precision, keep_rows and r == 0)
+                keys = [(group(s), beta, taker[(r, a + i)])
+                        if (r, a + i) in taker else None
+                        for i, s in enumerate(part)]
+                fold(sums, keys, part, flat, sizes, server_precision)
+                if r == 0:
+                    fold(blocks, [None if share and s in used0 else
+                                  block_key(s, s in used0, N, by_orbit)
+                                  for s in part], part, flat, sizes)
+                    if kept is not None:
+                        parts.append(kept)
+                del flat, kept
+            if r == 0 and share:
+                for (g, _e, j), b in sums.items():
+                    if j == 0:
+                        blocks[(g, True)] = b
         sats = [mm[1] for mm in sl.models]
+        orbit_of = {key: (key[0] if by_orbit else key[0] // N)
+                    for key in sums if key[2] == beta}
         groups = [[i] for i in range(len(sats))]
         new_d = {}
         if grouping is not None:
-            new = [i for i, s in enumerate(sats)
-                   if not grouping.known(s // N)]
+            new = list(dict.fromkeys(s // N for s in sats
+                                     if not grouping.known(s // N)))
             if new:
-                new_d = orbit_distances([sats[i] for i in new], flat[new],
-                                        sizes, w0_flat, N, server_precision)
+                got = block_distances([(o, sums[key])
+                                       for key, o in orbit_of.items()
+                                       if o in new], w0_flat)
+                new_d = {o: got[o] for o in new}
                 for o, d in new_d.items():
                     grouping.add(o, d)
             groups = [[i for i, s in enumerate(sats) if s // N in g]
@@ -496,10 +653,35 @@ def lap(cfg: Dict, spec: Dict, seed: int, *, local_iters: int,
         ws, base_w, gamma, stale_groups = rule.weights(
             [(s, sizes[s], mm[2]) for s, mm in zip(sats, sl.models)],
             beta, groups)
-        w = contract(ws, flat, base_w, w, server_precision)
-        first.setdefault("dists", new_d)
+        ws = np.asarray(ws, np.float64)
+        if sum(len(sums[key].sats) for key in orbit_of) != len(sats):
+            raise AssertionError(f"commit {beta} takes models of no block")
+        cs, rows = [], []
+        for key in orbit_of:
+            idx = [i for i, mm in enumerate(sl.models)
+                   if (group(mm[1]), mm[2]) == key[:2]]
+            if sorted(sats[i] for i in idx) != sorted(sums[key].sats):
+                raise AssertionError(f"block {key} holds other models than "
+                                     f"commit {beta} takes")
+            sz = np.array([float(sizes[sats[i]]) for i in idx])
+            c = float(ws[idx].sum() / sz.sum())
+            if np.max(np.abs(ws[idx] - c * sz)) > 1e-9 * np.max(np.abs(ws)):
+                raise ValueError(f"rule {spec['agg_mode']!r}: the weights of "
+                                 f"block {key} are not in proportion to the "
+                                 "data sizes; its BLOCKS must be 'model'")
+            cs.append(c)
+            rows.append(sums.pop(key).sum)
+        w = contract(cs, rows, base_w, w, server_precision)
+        del rows
+        if beta == 0:
+            dists = new_d
         commits.append(Commit(
             sl.t_agg, [(s, mm[2]) for s, mm in zip(sats, sl.models)],
             {s: float(v) for s, v in zip(sats, ws)}, float(base_w),
-            float(gamma), int(stale_groups), groups, w))
-    return Lap(schedule=slots, sizes=sizes, w0=w0, commits=commits, **first)
+            float(gamma), int(stale_groups), groups,
+            change_norms(w, w0_flat, slices), w if beta == 0 else None))
+    rows0 = ({k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
+             if parts else None)
+    return Lap(schedule=slots, participants=list(rounds[0]), rows=rows0,
+               used=used0, sizes=sizes, w0=w0, dists=dists, commits=commits,
+               blocks=blocks)

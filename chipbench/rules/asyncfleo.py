@@ -11,6 +11,9 @@ stale models joins, discounted by eq. 13.
 import numpy as np
 
 GROUPING = True
+# within one commit, the models of one orbit trained from one epoch weigh
+# in proportion to their data sizes (eq. 14; Alg. 2 selects whole orbits)
+BLOCKS = "orbit"
 MIN_GAMMA = 0.1
 
 

@@ -8,6 +8,9 @@ in commit order.
 import numpy as np
 
 GROUPING = False
+# each model's weight follows its place in the commit's order, not its
+# data size: a block is one model
+BLOCKS = "model"
 
 
 def round_commits(arrivals, t_start, cfg):

@@ -74,7 +74,11 @@ def measure(bench, seconds: float, trace: bool, dev, peak):
     if trace:
         jax.profiler.stop_trace()
     setup_s = bench.t_start - T_PROCESS
-    stats = dev.memory_stats() or {}
+    # the peak on the fullest of the cell's chips
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in [dev] + _devices()[1:bench.cell.chips]]
+    peaks = [v for v in peaks if v is not None]
+    peak_bytes = max(peaks) if peaks else None
     failed = sum(1 for a in bench.window_accuracies
                  if not math.isfinite(float(a)))
     lines = [f"window: {bench.commits} commits in {bench.lap_count} laps, "
@@ -104,25 +108,31 @@ def measure(bench, seconds: float, trace: bool, dev, peak):
         extra = {}
     result["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": len(_devices()),
-                        "memory_peak_bytes": stats.get("peak_bytes_in_use"),
+                        "memory_peak_bytes": peak_bytes,
                         **extra}
     return result, lines
 
 
-def verify(cell, prog, seed: int, local_iters: int, ref=None):
+def verify(cell, prog, seed: int, local_iters: int, ref=None, w0=None):
     """The captured first commits (``check.host_capture``) against the
-    reference of ``seed`` (a run seed).  Returns (correct, the numbers
-    with their limits, lines for standard error)."""
+    reference of ``seed`` (a run seed; ``w0`` its initial model where made
+    already).  Returns (correct, the numbers with their limits, lines for
+    standard error)."""
     from chipbench import check, reference
     cfg = cell.config
     t0 = time.perf_counter()
     if ref is None:
         ref = reference.lap(cfg, cell.mix["spec"], seed,
-                            local_iters=local_iters, root=cell.root)
+                            local_iters=local_iters, root=cell.root, w0=w0)
     nums = check.compare(prog, ref, cfg["constellation"]["sats_per_orbit"])
     ok, lines = check.judge(nums, cfg["limits"])
     lines.insert(0, f"reference check {time.perf_counter() - t0!r} s, "
-                    f"{len(ref.commits)} commits followed")
+                    f"{len(ref.commits)} commits followed, the program's "
+                    f"step output {prog['form']!r}")
+    if prog["row_norms"] is None and prog["form"] == "block_sums":
+        lines.insert(1, "row_change_gap not compared: the program returns "
+                        "block sums, not its bank; block_change_gap compares "
+                        "the blocks")
     checks = {k: {"value": v, "limit": cfg["limits"].get(k)}
               for k, v in nums.items()}
     return ok, checks, lines
@@ -131,16 +141,17 @@ def verify(cell, prog, seed: int, local_iters: int, ref=None):
 def run_cell(cell, seed: int, seconds: float, trace: bool, dev, peak):
     """Set-up, window, metrics, then the reference check once the
     program's state is freed.  Returns the result and the stderr lines."""
-    from chipbench import check, harness
+    from chipbench import check, harness, reference
 
     bench = harness.Bench(cell, seed).build()
     bench.warm()
     result, lines = measure(bench, seconds, trace, dev, peak)
-    prog = check.host_capture(bench.capture)
     run_seed, iters = bench.seed, bench.local_iters
+    w0 = reference.initial_model(cell.config, run_seed, cell.root)
+    prog = check.host_capture(bench.capture, w0)
     del bench
     gc.collect()
-    ok, checks, more = verify(cell, prog, run_seed, iters)
+    ok, checks, more = verify(cell, prog, run_seed, iters, w0=w0)
     result["correct"] = bool(ok and result["failed"] == 0)
     result["checks"] = checks          # the last key of the result line
     return result, lines + more

@@ -71,6 +71,10 @@ def xent(params, x, y, p):
     return (jax.nn.logsumexp(z, axis=-1) - gold).mean()
 
 
+# jitted local training by (lr, batch, local_iters, precision)
+_TRAIN = {}
+
+
 def train_participants(w, inputs_sel, ids, epoch_seed, cfg, local_iters,
                        precision="highest"):
     """``local_iters`` SGD steps per participant, minibatches drawn with key
@@ -99,7 +103,11 @@ def train_participants(w, inputs_sel, ids, epoch_seed, cfg, local_iters,
         return (jax.tree.map(lambda a: a.astype(jnp.float32), params),
                 losses.astype(jnp.float32).mean())
 
-    fn = jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0, None)))
+    key = (lr, bs, local_iters, precision)
+    fn = _TRAIN.get(key)
+    if fn is None:
+        fn = _TRAIN[key] = jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0,
+                                                          None)))
     return fn(w, jnp.asarray(imgs), jnp.asarray(labs),
               jnp.asarray(ids, jnp.int32), jnp.uint32(epoch_seed))
 

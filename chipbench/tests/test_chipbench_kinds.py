@@ -78,9 +78,10 @@ def test_a_model_kind_is_new_files_only(tmp_path):
     result, _lines = bench_run.measure(bench, 0.0, False, jax.devices()[0],
                                        PEAK)
     assert result["attempted"] >= 1
-    ok, checks, lines = bench_run.verify(cell,
-                                         check.host_capture(bench.capture),
-                                         bench.seed, bench.local_iters)
+    w0 = reference.initial_model(cell.config, bench.seed, root)
+    ok, checks, lines = bench_run.verify(
+        cell, check.host_capture(bench.capture, w0), bench.seed,
+        bench.local_iters, w0=w0)
     assert ok, lines
     assert checks["sched_diff"]["value"] == 0
     assert _snapshot(os.path.join(ROOT, "chipbench")) == before
