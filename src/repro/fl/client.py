@@ -15,9 +15,11 @@ Both pools expose three result forms:
   returning a device-resident ``ModelBank`` (stacked ``(C, N)`` float32,
   see DESIGN.md §2) and *lazy* device losses (``np.asarray`` only at
   history-record time, so timing math overlaps training dispatch).
-  Participant counts are padded up to power-of-two buckets so a changing
-  number of participants hits at most O(log S) traces instead of one per
-  distinct count.
+  Participant counts are padded up to ``modelbank.bucket_rows`` buckets
+  (powers of two up to 8, then multiples of 8 with at most 8 buckets an
+  octave: 40 participants train as 40 rows, at most 12.5% padding) so a
+  changing number of participants hits at most O(log S) traces instead of
+  one per distinct count.
 * ``train_many`` — legacy form materializing per-satellite host pytrees
   (one ``device_get``); kept for callers that need pytrees.
 

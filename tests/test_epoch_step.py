@@ -205,6 +205,71 @@ def test_carry_capacity_buckets():
     assert next_pow2(1) == 1 and next_pow2(3) == 4
 
 
+# ---- padded participant rows are invisible --------------------------------
+
+def _paper_lap(monkeypatch, bucket=None):
+    """Four commits of the paper constellation (5 orbits x 8, a tiny CNN,
+    J=1) with the fused program's dispatches and grouping distances
+    recorded; ``bucket`` replaces the participant-row bucket rule."""
+    import dataclasses
+    from repro.configs import MNIST_CNN
+    from repro.core import modelbank
+    from repro.core.grouping import GroupingState
+    from repro.launch.fl_train import build_run
+
+    if bucket is not None:
+        monkeypatch.setattr(modelbank, "bucket_rows", bucket)
+    steps, dists = [], []
+    step, assign = EpochStepProgram.step, GroupingState.assign_distances
+
+    def spy_step(self, *args, **kw):
+        out = step(self, *args, **kw)
+        steps.append((np.array(args[3]), np.array(args[5]),
+                      np.array(args[6]), float(args[7]),
+                      np.asarray(out[1])))
+        return out
+
+    def spy_assign(self, new_orbits, ds):
+        dists.append((list(new_orbits), np.array(ds)))
+        return assign(self, new_orbits, ds)
+
+    monkeypatch.setattr(EpochStepProgram, "step", spy_step)
+    monkeypatch.setattr(GroupingState, "assign_distances", spy_assign)
+    cfg = dataclasses.replace(MNIST_CNN, conv_channels=(2, 4), hidden=8)
+    fls, w0 = build_run(cfg, get_strategy("asyncfleo-hap"),
+                        SimConfig(event_driven=True, duration_s=86400.0),
+                        local_iters=1)
+    hist = fls.run(w0, max_epochs=4)
+    monkeypatch.undo()
+    return hist, steps, dists
+
+
+def test_padded_rows_change_nothing_real(monkeypatch):
+    """The paper's 40 participants train as 40 rows; padding them to the
+    old power-of-two bucket (64) changes no commit time, weight, real
+    row, accuracy or orbit distance."""
+    hist, steps, dists = _paper_lap(monkeypatch)
+    hist_p, steps_p, dists_p = _paper_lap(monkeypatch, bucket=next_pow2)
+    assert len(steps) == len(steps_p) == len(hist) == 4
+    assert [len(s[0]) for s in steps] == [40] * 4
+    assert [len(s[0]) for s in steps_p] == [64] * 4
+    assert ([r.time_s for r in hist] == [r.time_s for r in hist_p])
+    np.testing.assert_allclose([r.accuracy for r in hist],
+                               [r.accuracy for r in hist_p], rtol=1e-6)
+    for (ids, wv, wv_carry, base_w, bank), \
+            (ids_p, wv_p, wv_carry_p, base_w_p, bank_p) in zip(steps, steps_p):
+        n = len(ids)
+        assert np.array_equal(ids, ids_p[:n])
+        assert np.array_equal(wv, wv_p[:n]) and not wv_p[n:].any()
+        assert np.array_equal(wv_carry, wv_carry_p) and base_w == base_w_p
+        assert (np.max(np.abs(bank - bank_p[:n]))
+                <= 1e-6 * np.max(np.abs(bank_p[:n])))
+    assert len(dists) == len(dists_p) > 0
+    for (orbits, d), (orbits_p, d_p) in zip(dists, dists_p):
+        assert orbits == orbits_p
+        np.testing.assert_allclose(d, d_p, rtol=1e-6)
+
+
 # ---- stale + new-orbit fallback -------------------------------------------
 
 def _staged_downlink(fls, visible_epochs):

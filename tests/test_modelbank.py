@@ -163,6 +163,48 @@ def test_pad_ids_empty():
     assert n == 0 and len(ids) == 0
 
 
+_COUNTS = np.arange(1, 10_001)
+
+
+def _buckets():
+    from repro.core.modelbank import bucket_rows
+    return np.array([bucket_rows(int(n)) for n in _COUNTS])
+
+
+def _per_octave(b):
+    # octave k holds the counts in (2^(k-1), 2^k]
+    octave = np.array([int(n - 1).bit_length() for n in _COUNTS])
+    return max(len(set(b[octave == k])) for k in set(octave))
+
+
+_BUCKET_RULES = {
+    "at_least_n": lambda b: np.all(b >= _COUNTS),
+    "pow2_up_to_8": lambda b: list(b[:8]) == [1, 2, 4, 4, 8, 8, 8, 8],
+    "multiple_of_8_above_8": lambda b: np.all(b[8:] % 8 == 0),
+    "pad_under_8_rows_to_64": lambda b: np.all((b - _COUNTS)[:64] < 8),
+    "pad_at_most_one_eighth_above_64":
+        lambda b: np.all((b - _COUNTS)[64:] * 8 <= _COUNTS[64:]),
+    "at_most_8_buckets_an_octave": lambda b: _per_octave(b) <= 8,
+    "paper_40_and_10k": lambda b: b[39] == 40 and b[9_999] == 10_240,
+}
+
+
+@pytest.mark.parametrize("rule", sorted(_BUCKET_RULES))
+def test_bucket_rows_rule(rule):
+    """The participant-row bucket over n = 1..10,000: never below n, powers
+    of two up to 8, multiples of 8 above (the 4-chip split), little
+    padding, and few buckets, so few traces."""
+    assert _BUCKET_RULES[rule](_buckets())
+
+
+def test_pad_bucket_ids_repeats_the_first_id():
+    from repro.core.modelbank import pad_bucket_ids
+    ids, n = pad_bucket_ids([5, 3, 9] + list(range(10, 50)))
+    assert n == 43 and len(ids) == 48
+    assert list(ids[:n]) == [5, 3, 9] + list(range(10, 50))
+    assert set(ids[n:].tolist()) == {5}
+
+
 def test_combine_stacked_segments_match_single_bank():
     """Models split over two device matrices combine identically to one."""
     models = _models([1.0, -2.0, 0.5, 3.0])

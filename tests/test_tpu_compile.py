@@ -26,10 +26,12 @@ from repro.kernels.pairwise_dist.kernel import pairwise_dist_sq
 from repro.launch.mesh import make_mesh
 from repro.models import cnn
 
-BANK_ROWS = 64            # the S=40 paper participants, pow2-bucketed
+# the S=40 paper participants, their own row bucket (modelbank.bucket_rows),
+# and a 64-row bank, as 64 participants (8 orbits of 8) would train
+BANK_ROWS = (40, 64)
 PAPER_PARAMS = 206_922    # MNIST_CNN at its published widths
 CARRY_ROWS = 4
-NEW_ORBITS = 8            # kpad: the paper's 5 orbits, pow2-bucketed
+NEW_ORBITS = 8            # kpad: the paper's 5 orbits, a power-of-two bucket
 SHARD = 100               # images per satellite (4000 over 40)
 
 
@@ -63,11 +65,12 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_fed_agg_compiles_at_paper_bank(one_chip):
+@pytest.mark.parametrize("rows", BANK_ROWS)
+def test_fed_agg_compiles_at_paper_bank(one_chip, rows):
     f32 = jnp.float32
     compiled = fed_agg_flat.lower(
-        _sds((BANK_ROWS, PAPER_PARAMS), f32, one_chip),
-        _sds((BANK_ROWS,), f32, one_chip), _sds((PAPER_PARAMS,), f32, one_chip),
+        _sds((rows, PAPER_PARAMS), f32, one_chip),
+        _sds((rows,), f32, one_chip), _sds((PAPER_PARAMS,), f32, one_chip),
         _sds((), f32, one_chip), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -113,9 +116,9 @@ def _paper_program(mesh=None, use_kernel=False):
                             use_kernel=use_kernel)
 
 
-def _epoch_args(sharding):
+def _epoch_args(sharding, c):
     f32, i32 = jnp.float32, jnp.int32
-    n, c = PAPER_PARAMS, BANK_ROWS
+    n = PAPER_PARAMS
     return (_sds((n,), f32, sharding), _sds((CARRY_ROWS, n), f32, sharding),
             (_sds((c, SHARD, 28, 28, 1), f32, sharding),
              _sds((c, SHARD), i32, sharding)),
@@ -127,37 +130,42 @@ def _epoch_args(sharding):
             _sds((n,), f32, sharding))
 
 
-def _assert_xla_convolutions(hlo: str):
+def _assert_xla_convolutions(hlo: str, rows: int):
     """The CNN's 3x3 convs are XLA convolutions (``models/cnn._conv``'s
     TPU branch): no nine-tap im2col patches, no branch left at run time."""
     assert any(" convolution(" in line and "window={size=3x3" in line
                for line in hlo.splitlines())
     assert not re.search(r"\[[0-9,]*,9,(1|16)\]", hlo)  # the 9-tap axis
-    assert "[64,32,28,28,1,1]" not in hlo  # conv1's shifted slices
+    assert f"[{rows},32,28,28,1,1]" not in hlo  # conv1's shifted slices
     assert " conditional(" not in hlo
 
 
-def test_fused_epoch_program_runs_xla_convolutions(one_chip):
+@pytest.mark.parametrize("rows", BANK_ROWS)
+def test_fused_epoch_program_runs_xla_convolutions(one_chip, rows):
     prog = _paper_program()
     _assert_xla_convolutions(
-        prog._step.lower(*_epoch_args(one_chip)).compile().as_text())
+        prog._step.lower(*_epoch_args(one_chip, rows)).compile().as_text(),
+        rows)
 
 
-def test_fused_epoch_program_compiles_with_fed_agg(one_chip, monkeypatch):
+@pytest.mark.parametrize("rows", BANK_ROWS)
+def test_fused_epoch_program_compiles_with_fed_agg(one_chip, monkeypatch,
+                                                   rows):
     # the kernel picks interpret mode from the attached platform (CPU
     # here); the described chip needs the compiled kernel
     monkeypatch.setattr("repro.kernels.fed_agg.ops.default_interpret",
                         lambda: False)
     prog = _paper_program(use_kernel=True)
-    compiled = prog._step.lower(*_epoch_args(one_chip)).compile()
+    compiled = prog._step.lower(*_epoch_args(one_chip, rows)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_epoch_program_compiles_on_4_chip_mesh(topo):
+@pytest.mark.parametrize("rows", BANK_ROWS)
+def test_fused_epoch_program_compiles_on_4_chip_mesh(topo, rows):
     mesh = make_mesh((4, 1), ("data", "model"), devices=topo.devices)
     prog = _paper_program(mesh=mesh)
     compiled = prog._step.lower(
-        *_epoch_args(NamedSharding(mesh, P()))).compile()
+        *_epoch_args(NamedSharding(mesh, P()), rows)).compile()
     text = compiled.as_text()
     assert "all-reduce" in text                 # the bank contraction psum
-    _assert_xla_convolutions(text)
+    _assert_xla_convolutions(text, rows)
